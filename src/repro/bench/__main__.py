@@ -16,6 +16,8 @@ from __future__ import annotations
 import argparse
 import sys
 
+from repro.launch.compile_cache import enable_compile_cache
+
 from . import suite
 
 
@@ -46,6 +48,7 @@ def main(argv=None) -> int:
     for flag in unknown:
         print(f"ignoring unknown argument {flag!r}", file=sys.stderr)
 
+    enable_compile_cache()
     mode = "smoke" if args.smoke else ("full" if args.full else "quick")
     if args.out is None:
         # only smoke mode may touch the committed baseline by default —

@@ -14,17 +14,16 @@ Two FLOP/byte estimators, in preference order:
   bytes from XLA's cost analysis with an input+output-buffer fallback.
 * :func:`analytic_counts` — closed-form per-op estimates from the entry's
   ``meta`` (op, B, L, d, depth), used when no callable is available
-  (checks, subprocess timings) or when lowering fails.  Documented lower
+  (checks) or when lowering fails.  Documented lower
   bounds, same spirit as the seed's ``sig_model_flops``.
 
-Peaks come from :func:`peaks`: TPU uses datasheet constants (v5e bf16 MXU
-197 TFLOP/s, 819 GB/s HBM); CPU/GPU run two tiny **measured** probes once
-per process (a matmul for peak FLOP/s, a copy for bandwidth) so the
-achieved fractions mean something on the machine that produced the JSON.
+Peaks come from :func:`peaks`: a TPU reads the :data:`TPU_PEAKS` table by
+``device_kind`` (an unknown TPU raises); CPU/GPU run two tiny **measured**
+probes once per process (a matmul for peak FLOP/s, a copy for bandwidth).
 
-Everything here is fail-open and non-gating: a roofline field that cannot
-be computed degrades to fewer keys, never to an exception, and
-``compare.py`` only ever *reports* achieved-fraction deltas.
+The estimators are fail-open and non-gating: a count that cannot be
+computed degrades to fewer keys, and ``compare.py`` only ever *reports*
+achieved-fraction deltas.
 
 CLI::
 
@@ -46,17 +45,26 @@ import jax.numpy as jnp
 
 from . import timer
 
-#: TPU v5e datasheet peaks (bf16 MXU FLOP/s, HBM bytes/s) — the target
-#: machine of the Pallas kernels; other TPU generations are close enough
-#: for bound attribution, which only needs order-of-magnitude peaks
-PEAK_TPU_FLOPS = 197e12
-PEAK_TPU_BW = 819e9
+#: Per-chip peaks of the TPUs this repo targets, keyed by
+#: ``jax.devices()[0].device_kind``.  ``flops`` is the MXU bf16 peak,
+#: ``vpu_flops`` the f32 vector-unit peak that bounds the PDE wavefront.
+TPU_PEAKS: Dict[str, Dict] = {
+    "TPU v5 lite": {
+        "flops": 197e12,
+        "vpu_flops": 8 * 128 * 4 * 1.5e9,
+        "bandwidth": 819e9,
+        "source": "Google Cloud 'TPU v5e' documentation (197 TFLOP/s bf16, "
+                  "819 GB/s HBM); VPU f32 derived as 8x128 lanes x 4 VALU "
+                  "slots x 1.5 GHz (clock = 197e12 / (4 MXU x 128^2 x 2); "
+                  "the VALU count is not from a datasheet)",
+    },
+}
 
 #: elementwise VPU flops per refined PDE cell (the 2nd-order Goursat
 #: update: two poly evals in the Δ term + 3 multiply-adds)
 _PDE_FLOPS_PER_CELL = 10.0
 
-_peaks_memo: Optional[Dict[str, float]] = None
+_peaks_memo: Optional[Dict] = None
 
 
 def _measured_peaks() -> Dict[str, float]:
@@ -79,23 +87,28 @@ def _measured_peaks() -> Dict[str, float]:
 
     t_cp = timer.bench(cp, big, repeats=3, warmup=1)
     bw = 2.0 * big.size * 4 / max(t_cp, 1e-9)  # read + write
-    return {"flops": flops, "bandwidth": bw, "source": "measured"}
+    return {"flops": flops, "vpu_flops": flops, "bandwidth": bw,
+            "source": "measured"}
 
 
-def peaks() -> Dict[str, float]:
-    """Per-platform peak FLOP/s + bytes/s (memoised once per process)."""
+def peaks() -> Dict:
+    """Per-device peak FLOP/s + bytes/s (memoised once per process).
+
+    TPUs read :data:`TPU_PEAKS`; a TPU missing from it is an error, not a
+    guess.  CPU/GPU hosts get the measured probes.
+    """
     global _peaks_memo
     if _peaks_memo is None:
-        try:
-            if jax.default_backend() == "tpu":
-                _peaks_memo = {"flops": PEAK_TPU_FLOPS,
-                               "bandwidth": PEAK_TPU_BW,
-                               "source": "datasheet"}
-            else:
-                _peaks_memo = _measured_peaks()
-        except Exception:
-            _peaks_memo = {"flops": 0.0, "bandwidth": 0.0,
-                           "source": "unavailable"}
+        dev = jax.devices()[0]
+        if dev.platform == "tpu":
+            if dev.device_kind not in TPU_PEAKS:
+                raise KeyError(
+                    f"no peak table entry for TPU device_kind "
+                    f"{dev.device_kind!r}; add it to TPU_PEAKS with its "
+                    f"source")
+            _peaks_memo = TPU_PEAKS[dev.device_kind]
+        else:
+            _peaks_memo = _measured_peaks()
     return _peaks_memo
 
 
@@ -253,7 +266,9 @@ def attach(entry: dict, fn=None, args: tuple = ()) -> dict:
     otherwise — or when lowering fails — the analytic model from the
     entry's meta applies; when even that has nothing, the dict still
     carries the platform peaks so every bench entry has roofline fields.
+    A TPU missing from :data:`TPU_PEAKS` raises.
     """
+    peaks()
     try:
         seconds = entry.get("seconds")
         counts = None

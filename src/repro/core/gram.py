@@ -919,6 +919,8 @@ def sigkernel_gram_sharded(X: jax.Array, Y: Optional[jax.Array] = None, *,
             raise ValueError(
                 f"mesh has no {ax!r} axis (axes: {tuple(mesh.shape)}); "
                 "pass row_axis=/col_axis= matching your mesh")
+    # check_vma=False: the Pallas calls in the local solves declare their
+    # outputs without mesh-axis variance
     shard_map = get_shard_map()
     nd, nm = mesh.shape[row_axis], mesh.shape[col_axis]
 
@@ -948,7 +950,8 @@ def sigkernel_gram_sharded(X: jax.Array, Y: Optional[jax.Array] = None, *,
             local, mesh=mesh,
             in_specs=(P((row_axis, col_axis)), P((row_axis, col_axis)),
                       P()),
-            out_specs=P((row_axis, col_axis)))(a_dev, b_dev, sX)
+            out_specs=P((row_axis, col_axis)), check_vma=False)(
+                a_dev, b_dev, sX)
         # undo the deal: global pair t·D + r sits at device r, slot t
         k = k_dev.reshape(D, n_loc).T.reshape(-1)[:n_pairs]
         K = jnp.zeros((Bx, Bx), k.dtype).at[a_idx, b_idx].set(k)
@@ -979,6 +982,6 @@ def sigkernel_gram_sharded(X: jax.Array, Y: Optional[jax.Array] = None, *,
 
     Kp = shard_map(local, mesh=mesh,
                    in_specs=(P(row_axis), P(col_axis)),
-                   out_specs=P(row_axis, col_axis))(sXp, sYp)
+                   out_specs=P(row_axis, col_axis), check_vma=False)(sXp, sYp)
     K = Kp[jnp.asarray(invR)][:, jnp.asarray(invC)][:Bx, :By]
     return shard(K, "batch", "model")

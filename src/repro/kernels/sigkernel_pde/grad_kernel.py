@@ -7,11 +7,15 @@ One reverse wavefront pass per strip computes the adjoint
 and accumulates   dΔ[i,j] += g[i+1,j+1]·[(k̂[i+1,j]+k̂[i,j+1])·A'(Δ) − k̂[i,j]·B'(Δ)]
 
 folding refined cells back onto the unrefined Δ block.  Strips are processed
-bottom-up (grid index maps reverse the strip order); the adjoint row handed to
-the strip above overwrites the carried row in place (reads trail writes — the
-mirror image of the forward trick).  k̂ inside the strip is RECOMPUTED from the
-forward's checkpoint row — O(nx·ny/T) saved state instead of the full grid,
-a beyond-paper improvement (the paper stores the full grid / recomputes fully).
+bottom-up (grid index maps reverse the strip order).  Every adjoint wavefront
+row is stored at its sublane of a (W, T) scratch; the strip above reads lanes
+0/1 of the rows of the strip below from it and overwrites them in place
+(reads trail writes — the mirror image of the forward trick).  k̂ inside the
+strip is RECOMPUTED from the forward's checkpoint — O(nx·ny/T) saved state
+instead of the full grid, a beyond-paper improvement (the paper stores the
+full grid / recomputes fully).  The skewed dΔ is unskewed by the forward's
+row shear (rows stored in reverse order so the shear is a right rotation)
+and folded back onto the unrefined block by 0/1 matmuls.
 
 Skew/lane conventions match ``kernel.py``:
 cell (r, c) := refined update (i, j) = (strip_top + r, c), value k̂[i+1, c+1],
@@ -27,12 +31,12 @@ adjoint gains two −C terms::
 
 In lane terms the extra readers are G(r, c+2) (same lane, skew t+2 — the
 ``gnext2`` carry unshifted) and G(r+2, c) (two lanes down): lane T−2's reaches
-row 0 of the strip below (carried ``gbrow``) and lane T−1's reaches row 1 of
-the strip below, carried in a SECOND adjoint row ``gbrow2`` with coefficients
-from that strip's second refined Δ row.  The dΔ accumulation gains
+row 0 of the strip below and lane T−1's reaches row 1 of the strip below
+(lanes 0/1 of its stored adjoint rows), with coefficients from that strip's
+first two refined Δ rows.  The dΔ accumulation gains
 ``− (k̂[i+1,j−1] + k̂[i−1,j+1])·C'(Δ)``; the skew k̂ reads come from the
 recomputed strip (``ksk`` two skew-steps back) with lanes 1/0 falling back to
-the TWO checkpoint rows (brow, brow2) the order-2 forward saves per strip.
+lanes T−1/T−2 of the checkpoint.
 Boundary skew reads were the constant 1 in the forward and carry no adjoint.
 ``interior_dtype="bfloat16"`` recomputes k̂ with the forward's rounding but
 keeps every adjoint quantity f32 (straight-through gradient — see stencil.py).
@@ -47,227 +51,183 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from . import stencil
-from .kernel import (coeff_A, coeff_B, cps_rows, skew_to_ST, _expand_dyadic,
-                     vmem_scratch)
-
-
-def coeff_dA(p):
-    return 0.5 + p / 6.0
-
-
-def coeff_dB(p):
-    return -p / 6.0
+from .kernel import (check_strip, compiler_params, cps_lanes, expander, mm,
+                     refine, roll, shear, skew, strip_width, sweep,
+                     vmem_scratch, zeros_row)
 
 
 def bwd_kernel(delta_ref, delta_next_ref, cps_ref, gbar_ref, ddelta_ref,
-               ksk_ref, gbrow_ref, dsk_ref, gbrow2_ref=None, *,
-               T: int, lam1: int, lam2: int, ny: int, Ly: int,
+               s_ref, above_ref, ksk_ref, dn_ref, gst_ref, dsk_ref, *,
+               T: int, lam1: int, lam2: int, ny: int,
                scheme: str = "order1", interior_dtype: str = "float32"):
-    """One (batch, reversed-strip) grid step of the exact backward pass."""
-    s_rev = pl.program_id(1)
+    """One (batch, reversed-strip) grid step of the exact backward pass.
+
+    delta_ref / delta_next_ref: (1, R, Ly) Δ of this strip / the strip below.
+    cps_ref:  (1, 1, CR, W) the forward's checkpoint of this strip.
+    gbar_ref: (1, batch) upstream cotangents.
+    Scratch, all (W, T): skewed Δ, rows of the strip above (from the
+    checkpoint), recomputed k̂ rows, the strip below's first two refined Δ
+    rows as columns, adjoint rows (carried between strips), skewed dΔ.
+    """
+    b, s_rev = pl.program_id(0), pl.program_id(1)
+    W = s_ref.shape[0]
+    Ly = delta_ref.shape[2]
     n_steps = ny + T - 1
     order2 = scheme == "order2"
+    m1, m2 = (1 << lam1) - 1, (1 << lam2) - 1
 
     @pl.when(s_rev == 0)
     def _reset():
-        gbrow_ref[...] = jnp.zeros_like(gbrow_ref)
-        if gbrow2_ref is not None:
-            gbrow2_ref[...] = jnp.zeros_like(gbrow2_ref)
+        gst_ref[...] = jnp.zeros_like(gst_ref)
 
-    M = _expand_dyadic(delta_ref[0], lam1, lam2)            # (T, ny)
-    S_T = skew_to_ST(M, T, ny)                              # (ny+T, T)
-    S_Tp = jnp.pad(S_T, ((0, 2), (0, 0)))                   # safe t+2 reads
-    scale = 2.0 ** (-(lam1 + lam2))
-    # first refined Δ row of the strip below (coefficients for lane T-1)
-    d_next = jnp.repeat(delta_next_ref[0, 0:1, :], 2 ** lam2, axis=1) * scale
-    d_nextp = jnp.pad(d_next, ((0, 0), (0, T + 3)))         # (1, ny + T + 3)
-    if order2:
-        # second refined Δ row of the strip below (lane T-1's G(r+2, c) term)
-        row2 = 0 if lam1 else 1
-        d_next2 = jnp.repeat(delta_next_ref[0, row2:row2 + 1, :],
-                             2 ** lam2, axis=1) * scale
-        d_next2p = jnp.pad(d_next2, ((0, 0), (0, T + 3)))
+    s_ref[...] = skew(refine(delta_ref[0], T, W, lam1, lam2))
+    CR = cps_ref.shape[2]
+    tail = cps_ref[0, 0]                                    # (CR, W)
+    if CR < T:
+        tail = jnp.concatenate([jnp.zeros((T - CR, W), jnp.float32), tail])
+    above_ref[...] = tail.T
+    # dn[c, 0] / dn[c, 1]: refined rows 0 / 1 of the strip below at column c
+    nxt = refine(delta_next_ref[0], T, W, lam1, lam2)
+    row = jax.lax.broadcasted_iota(jnp.int32, nxt.shape, 0)
+    dn_ref[...] = jnp.where(row < 2, nxt, 0.0).T
+    dsk_ref[...] = jnp.zeros_like(dsk_ref)
 
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
-    zeros = jnp.zeros((1, T), jnp.float32)
-
-    # ---- phase 1: recompute strip interior k̂ from the checkpoint row -------
-    def fstep(t, carry):
-        prev, prev2 = carry
-        p = jax.lax.dynamic_slice(S_T, (t, 0), (1, T))
-        up0 = cps_ref[0, 0, t + 1]
-        upleft0 = cps_ref[0, 0, t]
-        shift_prev = jnp.where(lane == 0, up0, jnp.roll(prev, 1, axis=1))
-        shift_prev2 = jnp.where(lane == 0, upleft0, jnp.roll(prev2, 1, axis=1))
-        left = jnp.where(lane == t, 1.0, prev)
-        upleft = jnp.where(lane == t, 1.0, shift_prev2)
-        if order2:
-            # same data-gridline fallback as the forward (kernel.py)
-            edge = (lane % (1 << lam1) == 0) | ((t - lane) % (1 << lam2) == 0)
-            k_dl = jnp.where(lane >= t - 1, 1.0, prev2)
-            k_ul = jnp.roll(prev2, 2, axis=1)
-            k_ul = jnp.where(lane == 1, cps_ref[0, 0, t], k_ul)
-            k_ul = jnp.where(lane == 0, cps_ref[0, 1, t + 1], k_ul)
-            cur = ((left + shift_prev) * coeff_A(p)
-                   - upleft * stencil.coeff_B2_at(p, edge)
-                   - (k_dl + k_ul) * stencil.coeff_C2_at(p, edge))
-        else:
-            cur = (left + shift_prev) * coeff_A(p) - upleft * coeff_B(p)
-        cur = stencil.round_interior(cur, interior_dtype)
-        active = (lane <= t) & (lane > t - ny)
-        cur = jnp.where(active, cur, 0.0)
-        pl.store(ksk_ref, (pl.ds(t, 1), pl.ds(0, T)), cur)
-        return (cur, prev)
-
-    jax.lax.fori_loop(0, n_steps, fstep, (zeros, zeros))
+    # ---- phase 1: recompute strip interior k̂ from the checkpoint -----------
+    sweep(s_ref, above_ref, ksk_ref, T=T, lam1=lam1, lam2=lam2, ny=ny,
+          scheme=scheme, interior_dtype=interior_dtype)
 
     # ---- phase 2: reverse adjoint wavefront --------------------------------
-    gbar = gbar_ref[0]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
+    blane = jax.lax.broadcasted_iota(jnp.int32, gbar_ref.shape, 1)
+    gbar = jnp.sum(jnp.where(blane == b, gbar_ref[...], 0.0), axis=1,
+                   keepdims=True)
+
+    def rows(ref, i):
+        return ref[pl.ds(i, 1), :]
 
     def bstep(i, carry):
         t = n_steps - 1 - i
-        gnext, gnext2 = carry                               # G at skew t+1, t+2
-        cT = jnp.maximum(t - (T - 1), 0)                    # column of lane T-1
+        gnext, gnext2, g_q2 = carry                         # G at skew t+1, t+2
+        # lane T−1 sits in column t − T + 1 (q); lanes 0/1 of row q of the
+        # strip below hold its row-0/row-1 values at that column.  Row q2 =
+        # q + 1 was this step's predecessor's row q: carried, because this
+        # strip has already overwritten it when T = 1.
+        q = jnp.maximum(t - (T - 1), 0)
+        q2 = jnp.maximum(t - (T - 2), 0)
+        g_q = rows(gst_ref, q)
+        x_q, x_q2 = rows(dn_ref, q), rows(dn_ref, q2)
 
-        p_c = jax.lax.dynamic_slice(S_Tp, (t, 0), (1, T))       # Δ(r, c)
-        p_a = jax.lax.dynamic_slice(S_Tp, (t + 1, 0), (1, T))   # Δ(r, c+1)
-        p_t2 = jax.lax.dynamic_slice(S_Tp, (t + 2, 0), (1, T))  # Δ(r, c+2)
-        p_r1 = jnp.roll(p_a, -1, axis=1)                        # Δ(r+1, c)
-        p_r1c1 = jnp.roll(p_t2, -1, axis=1)                     # Δ(r+1, c+1)
-        # lane T-1 coefficients come from the strip below
-        p_r1 = jnp.where(lane == T - 1, d_nextp[0, cT], p_r1)
-        p_r1c1 = jnp.where(lane == T - 1, d_nextp[0, cT + 1], p_r1c1)
+        p_c = rows(s_ref, t)                                # Δ(r, c)
+        p_a = rows(s_ref, t + 1)                            # Δ(r, c+1)
+        p_t2 = rows(s_ref, t + 2)                           # Δ(r, c+2)
+        last = lane == T - 1
+        p_r1 = jnp.where(last, roll(x_q, -1), roll(p_a, -1))      # Δ(r+1, c)
+        p_r1c1 = jnp.where(last, roll(x_q2, -1), roll(p_t2, -1))  # Δ(r+1, c+1)
 
         g_right = gnext                                     # G(r, c+1)
-        g_down = jnp.roll(gnext, -1, axis=1)                # G(r+1, c)
-        g_downright = jnp.roll(gnext2, -1, axis=1)          # G(r+1, c+1)
-        g_down = jnp.where(lane == T - 1, gbrow_ref[0, cT + 1], g_down)
-        g_downright = jnp.where(lane == T - 1, gbrow_ref[0, cT + 2], g_downright)
+        g_down = jnp.where(last, roll(g_q, -1), roll(gnext, -1))  # G(r+1, c)
+        g_downright = jnp.where(last, roll(g_q2, -1),             # G(r+1, c+1)
+                                roll(gnext2, -1))
 
         if order2:
             # extra readers of k̂[a,b]: the cells whose skew neighbour it was
-            cT2 = jnp.maximum(t - (T - 2), 0)               # column of lane T-2
-            p_r2 = jnp.roll(p_t2, -2, axis=1)               # Δ(r+2, c)
-            p_r2 = jnp.where(lane == T - 2, d_nextp[0, cT2], p_r2)
-            p_r2 = jnp.where(lane == T - 1, d_next2p[0, cT], p_r2)
+            p_r2 = jnp.where(lane == T - 2, roll(x_q2, -2),       # Δ(r+2, c)
+                             jnp.where(last, roll(x_q, -2), roll(p_t2, -2)))
             g_right2 = gnext2                               # G(r, c+2)
-            g_down2 = jnp.roll(gnext2, -2, axis=1)          # G(r+2, c)
-            g_down2 = jnp.where(lane == T - 2, gbrow_ref[0, cT2 + 1], g_down2)
-            g_down2 = jnp.where(lane == T - 1, gbrow2_ref[0, cT + 1], g_down2)
+            g_down2 = jnp.where(lane >= T - 2, roll(g_q2, -2),    # G(r+2, c)
+                                roll(gnext2, -2))
             # per-WRITER gridline fallback (stencil.py): writer cells are
             # (r+1, c+1) for the −B term, (r, c+2) / (r+2, c) for the −C
             # terms; global row ≡ lane row (mod 2^λ1) because T is a
             # multiple of 2^λ1, so the masks hold across strip boundaries
-            m1, m2 = 1 << lam1, 1 << lam2
             col = t - lane
-            edge_b = ((lane + 1) % m1 == 0) | ((col + 1) % m2 == 0)
-            edge_cr = (lane % m1 == 0) | ((col + 2) % m2 == 0)
-            edge_cd = ((lane + 2) % m1 == 0) | (col % m2 == 0)
-            cur = (g_right * coeff_A(p_a) + g_down * coeff_A(p_r1)
+            edge_b = (((lane + 1) & m1) == 0) | (((col + 1) & m2) == 0)
+            edge_cr = ((lane & m1) == 0) | (((col + 2) & m2) == 0)
+            edge_cd = (((lane + 2) & m1) == 0) | ((col & m2) == 0)
+            cur = (g_right * stencil.coeff_A(p_a)
+                   + g_down * stencil.coeff_A(p_r1)
                    - g_downright * stencil.coeff_B2_at(p_r1c1, edge_b)
                    - g_right2 * stencil.coeff_C2_at(p_t2, edge_cr)
                    - g_down2 * stencil.coeff_C2_at(p_r2, edge_cd))
         else:
-            cur = (g_right * coeff_A(p_a) + g_down * coeff_A(p_r1)
-                   - g_downright * coeff_B(p_r1c1))
+            cur = (g_right * stencil.coeff_A(p_a)
+                   + g_down * stencil.coeff_A(p_r1)
+                   - g_downright * stencil.coeff_B1(p_r1c1))
         # seed ∂F/∂k̂[nx, ny] at the bottom-right cell of the bottom strip
         seed_here = (s_rev == 0) & (t == n_steps - 1)
-        cur = cur + jnp.where(seed_here & (lane == T - 1), gbar, 0.0)
+        cur = cur + jnp.where(seed_here & last, gbar, 0.0)
         active = (lane <= t) & (lane > t - ny)
         cur = jnp.where(active, cur, 0.0)
 
         # ---- dΔ contribution of cells on this anti-diagonal ----
-        k_tm1 = pl.load(ksk_ref, (pl.ds(jnp.maximum(t - 1, 0), 1), pl.ds(0, T)))
-        k_tm2 = pl.load(ksk_ref, (pl.ds(jnp.maximum(t - 2, 0), 1), pl.ds(0, T)))
+        k_tm1 = rows(ksk_ref, jnp.maximum(t - 1, 0))
+        k_tm2 = rows(ksk_ref, jnp.maximum(t - 2, 0))
+        a_1 = rows(above_ref, jnp.minimum(t + T - 1, W - 1))
+        a_2 = rows(above_ref, jnp.clip(t + T - 2, 0, W - 1))
         k_left = jnp.where(lane == t, 1.0, k_tm1)               # k̂[i+1, j]
-        k_up = jnp.where(lane == 0, cps_ref[0, 0, jnp.minimum(t + 1, ny + T)],
-                         jnp.roll(k_tm1, 1, axis=1))            # k̂[i, j+1]
-        k_upleft = jnp.where(lane == 0, cps_ref[0, 0, jnp.minimum(t, ny + T)],
-                             jnp.roll(k_tm2, 1, axis=1))
+        k_up = jnp.where(lane == 0, roll(a_1, 1), roll(k_tm1, 1))  # k̂[i, j+1]
+        k_upleft = jnp.where(lane == 0, roll(a_2, 1), roll(k_tm2, 1))
         k_upleft = jnp.where(lane == t, 1.0, k_upleft)          # k̂[i, j]
         if order2:
             k_dl = jnp.where(lane >= t - 1, 1.0, k_tm2)         # k̂[i+1, j-1]
-            k_ul = jnp.roll(k_tm2, 2, axis=1)                   # k̂[i-1, j+1]
-            k_ul = jnp.where(lane == 1,
-                             cps_ref[0, 0, jnp.minimum(t, ny + T)], k_ul)
-            k_ul = jnp.where(lane == 0,
-                             cps_ref[0, 1, jnp.minimum(t + 1, ny + T)], k_ul)
+            k_ul = jnp.where(lane < 2, roll(a_2, 2), roll(k_tm2, 2))
             # dΔ selects on the contributing cell (r, c) itself
-            edge_cell = (lane % (1 << lam1) == 0) \
-                | ((t - lane) % (1 << lam2) == 0)
-            contrib = cur * ((k_left + k_up) * coeff_dA(p_c)
+            edge_cell = ((lane & m1) == 0) | (((t - lane) & m2) == 0)
+            contrib = cur * ((k_left + k_up) * stencil.coeff_dA(p_c)
                              - k_upleft * stencil.coeff_dB2_at(p_c, edge_cell)
                              - (k_dl + k_ul)
                              * stencil.coeff_dC2_at(p_c, edge_cell))
         else:
-            contrib = cur * ((k_left + k_up) * coeff_dA(p_c)
-                             - k_upleft * coeff_dB(p_c))
+            contrib = cur * ((k_left + k_up) * stencil.coeff_dA(p_c)
+                             - k_upleft * stencil.coeff_dB1(p_c))
         contrib = jnp.where(active, contrib, 0.0)
-        pl.store(dsk_ref, (pl.ds(t, 1), pl.ds(0, T)), contrib)
+        dsk_ref[pl.ds(W - 1 - t, 1), :] = contrib           # reversed rows
+        gst_ref[pl.ds(t, 1), :] = cur
+        return (cur, gnext, g_q)
 
-        # hand the r = 0 adjoint row up to the strip above (in-place; reads at
-        # indices <= t-T+3 trail these writes in the reverse loop)
-        @pl.when(t <= ny - 1)
-        def _():
-            gbrow_ref[0, t + 1] = cur[0, 0]
-
-        if order2:
-            # hand the r = 1 adjoint row up as well (lane T-1's G(r+2, c))
-            @pl.when((t >= 1) & (t <= ny))
-            def _():
-                gbrow2_ref[0, t] = cur[0, 1]
-
-        return (cur, gnext)
-
-    jax.lax.fori_loop(0, n_steps, bstep, (zeros, zeros))
+    zeros = zeros_row(s_ref)
+    jax.lax.fori_loop(0, n_steps, bstep, (zeros, zeros, rows(gst_ref, ny)))
 
     # ---- phase 3: unskew + dyadic fold -> unrefined dΔ block ----------------
-    U = dsk_ref[...].T                                      # (T, n_steps)
-    rows = [jax.lax.dynamic_slice(U, (r, r), (1, ny)) for r in range(T)]
-    dM = jnp.concatenate(rows, axis=0)                      # (T, ny)
-    if lam1 or lam2:
-        dM = dM.reshape(T >> lam1, 1 << lam1, Ly, 1 << lam2).sum((1, 3))
-    dM = dM * scale
-    ddelta_ref[0] = dM.astype(ddelta_ref.dtype)
+    # dsk[W−1−t, r] holds cell (r, t − r): rotating lane-row r right by r
+    # puts cell (r, c) at lane W−1−c, which the reversed expander folds.
+    dM = mm(shear(dsk_ref[...].T), expander(W, Ly, lam2, reverse=True))
+    if lam1:
+        dM = mm(expander(T, T >> lam1, lam1, transpose=True), dM)
+    ddelta_ref[0] = dM * 2.0 ** (-(lam1 + lam2))
 
 
 def build_bwd(batch: int, Lx: int, Ly: int, *, T: int, lam1: int, lam2: int,
               interpret: bool, scheme: str = "order1",
               interior_dtype: str = "float32"):
-    from .kernel import check_strip
+    """Exact backward: ``f(delta, delta, cps, gbar (batch,)) -> dΔ`` of
+    shape (batch, Lx, Ly); Δ is passed twice (this strip / the strip below)."""
     R = check_strip(T, lam1, Lx, scheme)
     n_strips = Lx // R
-    nx, ny = Lx << lam1, Ly << lam2
-    n_steps = ny + T - 1
-    rows = cps_rows(scheme)
-
+    ny = Ly << lam2
+    W = strip_width(ny, T)
+    CR = cps_lanes(T)
     kern = functools.partial(bwd_kernel, T=T, lam1=lam1, lam2=lam2, ny=ny,
-                             Ly=Ly, scheme=scheme,
-                             interior_dtype=interior_dtype)
+                             scheme=scheme, interior_dtype=interior_dtype)
 
     def rev(s):
         return n_strips - 1 - s
 
-    scratch = [
-        vmem_scratch((n_steps, T)),        # recomputed k̂ (skewed)
-        vmem_scratch((1, ny + T + 3)),     # carried adjoint row
-        vmem_scratch((n_steps, T)),        # dΔ accumulator (skewed)
-    ]
-    if scheme == "order2":
-        scratch.append(vmem_scratch((1, ny + T + 3)))  # carried row-1 adjoint
-
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kern,
         grid=(batch, n_strips),
         in_specs=[
             pl.BlockSpec((1, R, Ly), lambda b, s: (b, rev(s), 0)),
             pl.BlockSpec((1, R, Ly),
                          lambda b, s: (b, jnp.minimum(rev(s) + 1, n_strips - 1), 0)),
-            pl.BlockSpec((1, rows, ny + T + 1), lambda b, s: (b, rev(s), 0)),
-            pl.BlockSpec((1,), lambda b, s: (b,)),
+            pl.BlockSpec((1, 1, CR, W), lambda b, s: (b, rev(s), 0, 0)),
+            pl.BlockSpec((1, batch), lambda b, s: (0, 0)),
         ],
         out_specs=pl.BlockSpec((1, R, Ly), lambda b, s: (b, rev(s), 0)),
         out_shape=jax.ShapeDtypeStruct((batch, Lx, Ly), jnp.float32),
-        scratch_shapes=scratch,
+        scratch_shapes=[vmem_scratch((W, T)) for _ in range(6)],
+        compiler_params=compiler_params(W, T, 6),
         interpret=interpret,
     )
+    return lambda delta, delta_next, cps, gbar: call(
+        delta, delta_next, cps, gbar.reshape(1, batch))

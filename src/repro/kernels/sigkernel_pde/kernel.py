@@ -1,43 +1,50 @@
 """Pallas TPU kernel: Goursat-PDE signature-kernel solver (pySigLib §3.3).
 
-TPU-native translation of the paper's GPU wavefront scheme (DESIGN.md §2):
+TPU-native translation of the paper's GPU wavefront scheme:
 
-* the PDE grid is swept in **row strips of T refined rows** (T = VPU lane
-  count, default 128) — the analogue of the paper's 32-thread blocks;
+* the PDE grid is swept in **row strips of T refined rows** (T ≤ 128 lanes)
+  — the analogue of the paper's 32-thread blocks;
 * inside a strip the anti-diagonal wavefront advances one skew-step per loop
-  iteration, carrying a **rotating pair of diagonal buffers** (``prev``,
-  ``prev2``) in registers/VMEM — the analogue of the paper's 3 rotating
-  anti-diagonals in CUDA shared memory;
-* the strip's bottom row **overwrites the carried boundary row in place**
-  (reads trail writes by T−1 steps), exactly the paper's trick of reusing the
-  initial-condition vector between blocks;
-* dyadic refinement is applied **on-the-fly**: Δ is expanded from the
-  unrefined (R, Ly) HBM block only inside VMEM (refined Δ never exists in
-  HBM), with R = T / 2^λ1 original rows per strip;
+  iteration: step t computes the (1, T) row of cells (r, c = t − r), lane r,
+  carrying the two previous rows (``prev``, ``prev2``) — the analogue of the
+  paper's 3 rotating anti-diagonals in CUDA shared memory;
+* every wavefront row is stored in a ``(W, T)`` VMEM scratch at sublane t.
+  The next strip reads the rows of the strip above from the same scratch
+  (lane T−1 of row t + T − 1 is k̂[strip_top, t + 1], moved to lane 0 by a
+  lane roll) and overwrites them in place — reads lead writes by T − 1
+  rows, the paper's trick of reusing the initial-condition vector between
+  blocks.  All accesses are whole rows at a dynamic sublane: no scalar ever
+  moves between VMEM and the vector unit;
+* dyadic refinement, zero padding to W lanes and the skew are built in VMEM
+  from the unrefined (R, Ly) block, R = T / 2^λ1: 0/1 expansion matrices
+  (exact under ``Precision.HIGHEST``) refine and pad, and one strided lane
+  roll followed by a transpose skews, S[t, r] = Δ_refined(r, t − r).  The
+  refined Δ never exists in HBM;
 * Δ itself is precomputed OUTSIDE the kernel by one batched MXU matmul
-  (paper design choice (2)) — see ``ops.py``.
+  (paper design choice (2)) — see ``ops.py`` — or, in the fused variants,
+  recomputed per strip from the increments.
 
 Grid = (batch, n_strips); TPU grid iteration is sequential per core, so VMEM
-scratch (the boundary row) persists across strips — the TPU-native replacement
-for CUDA inter-block synchronisation.
+scratch persists across strips — the TPU-native replacement for CUDA
+inter-block synchronisation.  Kernel values are written as lanes of one
+resident lane-dense output row.
 
-In grad mode the kernel additionally emits one **checkpoint row per strip**
-(k̂ at the strip's top boundary; two rows for the order-2 stencil, whose
-skew reads reach one row further back).  The backward kernel recomputes the
-strip interior from the checkpoint — O(nx·ny / T) activation memory instead
-of the full grid, a beyond-paper improvement (the paper stores the full
-grid).
+In grad mode the kernel additionally emits one **checkpoint** per strip:
+lanes T−CR … T−1 (CR = min(T, 8)) of the stored rows of the strip above,
+transposed into a lane-dense (CR, W) tile.  Lane T−1 holds the strip's top
+boundary row and lane T−2 the row above it (the order-2 stencil's extra
+skew read).  The backward kernel rebuilds the strip interior from it —
+O(nx·ny / T) activation memory instead of the full grid, a beyond-paper
+improvement (the paper stores the full grid).
 
 Scheme support (``GridConfig.scheme`` — coefficient sets in ``stencil.py``):
 the ``"order2"`` stencil reads the two anti-diagonal neighbours
-k̂_{i+1,j−1} / k̂_{i−1,j+1}, both living on the ``prev2`` rotating buffer
-(same lane / two lanes up).  Lane 1's k̂_{i−1,j+1} comes from the carried
-boundary row and lane 0's from a SECOND carried boundary row ``brow2``
-(= k̂[strip_top − 1, ·], written by each strip's row T−2, initialised to the
-boundary-of-ones extension), so results are independent of the strip height
-— order-2 requires T ≥ 2.  ``GridConfig.interior_dtype = "bfloat16"``
-rounds every freshly computed cell through bf16 (``stencil.round_interior``)
-while the carried boundary rows and the readout stay f32.
+k̂_{i+1,j−1} / k̂_{i−1,j+1}, both on ``prev2`` (same lane / two lanes up);
+lanes 0/1 of k̂_{i−1,j+1} come from lanes T−2/T−1 of the strip above, so
+results are independent of the strip height — order-2 requires T ≥ 2.
+``GridConfig.interior_dtype = "bfloat16"`` rounds every freshly computed
+cell through bf16 (``stencil.round_interior``) while the strip boundaries
+and the readout stay f32.
 """
 
 from __future__ import annotations
@@ -47,165 +54,242 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from . import stencil
 
-
-def coeff_A(p):
-    return 1.0 + 0.5 * p + (1.0 / 12.0) * p * p
-
-
-def coeff_B(p):
-    return 1.0 - (1.0 / 12.0) * p * p
+LANES = 128
+#: checkpoint lanes kept per strip (an aligned sublane tile once transposed)
+CPS_LANES = 8
 
 
-def skew_to_ST(M: jax.Array, T: int, n: int) -> jax.Array:
-    """(T, n) -> (n + T, T) skewed so that S_T[t, r] = M[r, t - r].
+def strip_width(ny: int, T: int) -> int:
+    """Sublanes W of the skewed strip buffers: ≥ ny + T + 1, lane-aligned.
 
-    Built with T contiguous row writes then one VMEM transpose.
+    The skew roll wraps nothing but zeros once W ≥ ny + T − 1, and the
+    backward reads Δ up to skew-step ny + T.
     """
-    S = jnp.zeros((T, n + T), M.dtype)
-    for r in range(T):
-        S = jax.lax.dynamic_update_slice(S, M[r:r + 1], (r, r))
-    return S.T
+    return -(-(ny + T + 1) // LANES) * LANES
 
 
-def _expand_dyadic(blk: jax.Array, lam1: int, lam2: int) -> jax.Array:
-    """On-the-fly VMEM expansion of an unrefined Δ block (R, Ly) to (T, ny)."""
-    scale = 2.0 ** (-(lam1 + lam2))
-    M = blk
+def cps_lanes(T: int) -> int:
+    return min(T, CPS_LANES)
+
+
+def vmem_limit(W: int, T: int, n_buffers: int) -> int:
+    """Scoped-VMEM request: ``n_buffers`` (W, T) scratches plus as many
+    (T, W) temporaries, lanes padded to 128, doubled for headroom."""
+    est = 4 * W * max(T, LANES) * (2 * n_buffers)
+    return min(96 * 2 ** 20, max(32 * 2 ** 20, est))
+
+
+def compiler_params(W: int, T: int, n_buffers: int):
+    return pltpu.CompilerParams(vmem_limit_bytes=vmem_limit(W, T, n_buffers))
+
+
+def roll(x: jax.Array, shift: int) -> jax.Array:
+    """Lane rotation with ``jnp.roll`` semantics (x[i] -> out[i + shift])."""
+    shift %= x.shape[-1]
+    return pltpu.roll(x, shift, x.ndim - 1) if shift else x
+
+
+def expander(n_fine: int, n_coarse: int, lam: int, *,
+             transpose: bool = False, reverse: bool = False) -> jax.Array:
+    """0/1 dyadic expansion matrix E[i, j] = [i >> lam == j], (n_fine,
+    n_coarse); fine indices past n_coarse << lam get a zero row (padding).
+    ``reverse`` counts i from the end; ``transpose`` returns Eᵀ."""
+    shape = (n_coarse, n_fine) if transpose else (n_fine, n_coarse)
+    i = jax.lax.broadcasted_iota(jnp.int32, shape, 1 if transpose else 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, shape, 0 if transpose else 1)
+    if reverse:
+        i = n_fine - 1 - i
+    return ((i >> lam) == j).astype(jnp.float32)
+
+
+def mm(a: jax.Array, b: jax.Array, *, nt: bool = False) -> jax.Array:
+    """f32 matmul a @ b (a @ bᵀ when ``nt``) at full precision."""
+    dims = (((1,), (1,) if nt else (0,)), ((), ()))
+    return jax.lax.dot_general(a, b, dims, precision=jax.lax.Precision.HIGHEST,
+                               preferred_element_type=jnp.float32)
+
+
+def refine(blk: jax.Array, T: int, W: int, lam1: int, lam2: int) -> jax.Array:
+    """Unrefined Δ block (R, Ly) -> refined, zero-padded (T, W) block."""
+    R, Ly = blk.shape
     if lam1:
-        M = jnp.repeat(M, 2 ** lam1, axis=0)
-    if lam2:
-        M = jnp.repeat(M, 2 ** lam2, axis=1)
-    return M * scale
+        blk = mm(expander(T, R, lam1), blk)
+    return mm(blk, expander(W, Ly, lam2), nt=True) * 2.0 ** (-(lam1 + lam2))
 
 
-def fused_fwd_kernel(dx_ref, dy_ref, out_ref, brow_ref, brow2_ref=None, *,
-                     T: int, lam1: int, lam2: int, ny: int,
-                     scheme: str = "order1", interior_dtype: str = "float32"):
-    """Fused-Δ forward: the strip's Δ block is computed ON THE FLY in VMEM as
-    dx_strip @ dyᵀ (an (R, d) × (d, Ly) MXU matmul) — Δ never exists in HBM.
+def refine_fused(dx: jax.Array, dy: jax.Array, T: int, W: int, lam1: int,
+                 lam2: int) -> jax.Array:
+    """Refined, zero-padded Δ block (T, W) straight from increments: the
+    (R, d) × (d, Ly) MXU matmul with both sides dyadically expanded first."""
+    if lam1:
+        dx = mm(expander(T, dx.shape[0], lam1), dx)
+    dy = mm(expander(W, dy.shape[0], lam2), dy)
+    return mm(dx, dy, nt=True) * 2.0 ** (-(lam1 + lam2))
 
-    Beyond-paper optimisation: pySigLib precomputes Δ with one bmm (design
-    choice (2)) because on GPU the bmm is the fast path; on TPU the Goursat
-    sweep is HBM-bound on streaming Δ (3·B²·L²·4 bytes for a Gram), so fusing
-    the tiny-K matmul into the wavefront kernel converts the workload from
-    memory-bound to compute-bound (EXPERIMENTS.md §Perf).
+
+def shear(M: jax.Array) -> jax.Array:
+    """Rotate row r of M right by r lanes.
+
+    A barrel shifter: log2(T) lane rotations, each applied to the rows with
+    that bit of r set.  (Interpret mode would unroll a strided rotation
+    into T pieces per call.)
     """
-    s = pl.program_id(1)
-
-    @pl.when(s == 0)
-    def _reset():
-        brow_ref[...] = jnp.ones_like(brow_ref)
-        if brow2_ref is not None:
-            brow2_ref[...] = jnp.ones_like(brow2_ref)
-
-    blk = jnp.dot(dx_ref[0], dy_ref[0].T,
-                  preferred_element_type=jnp.float32)      # (R, Ly) in VMEM
-    _wavefront(blk, out_ref, None, brow_ref, brow2_ref, T=T, lam1=lam1,
-               lam2=lam2, ny=ny, save_cps=False, scheme=scheme,
-               interior_dtype=interior_dtype)
+    row = jax.lax.broadcasted_iota(jnp.int32, M.shape, 0)
+    bit = 1
+    while bit < M.shape[0]:
+        M = jnp.where((row & bit) != 0, roll(M, bit), M)
+        bit <<= 1
+    return M
 
 
-def fwd_kernel(delta_ref, out_ref, cps_ref, brow_ref, brow2_ref=None, *,
-               T: int, lam1: int, lam2: int, ny: int, save_cps: bool,
-               scheme: str = "order1", interior_dtype: str = "float32"):
-    """One (batch, strip) grid step of the forward wavefront solver.
+def skew(M: jax.Array) -> jax.Array:
+    """(T, W) -> (W, T) with S[t, r] = M[r, t − r] (0 where t < r)."""
+    return shear(M).T
 
-    delta_ref: (1, R, Ly) unrefined Δ rows of this strip (VMEM block).
-    out_ref:   (1,) final kernel value k̂[nx, ny] (written every strip;
-               the last strip's write is the result).
-    cps_ref:   (1, cps_rows, ny + T + 1) checkpoint rows (grad mode only):
-               row 0 = brow; row 1 (order-2 only) = brow2.
-    brow_ref:  (1, ny + T + 1) scratch — carried boundary row
-               brow[c] = k̂[strip_top, c]; persists across grid steps.
-    brow2_ref: (1, ny + T + 1) scratch (order-2 only) — the row above it,
-               brow2[c] = k̂[strip_top − 1, c] (ones above the first strip).
+
+def sweep(s_ref, src_ref, dst_ref, *, T, lam1, lam2, ny, scheme="order1",
+          interior_dtype="float32"):
+    """Anti-diagonal sweep of one strip.
+
+    s_ref:   (W, T) skewed refined Δ of the strip.
+    src_ref: (W, T) wavefront rows of the strip above (ones above the first
+             strip); only lanes T−1 and T−2 are read.
+    dst_ref: (W, T) receives this strip's wavefront rows; may be ``src_ref``
+             (reads lead writes).
+    Returns the last row, whose lane T−1 is k̂[strip_bottom, ny].
     """
-    s = pl.program_id(1)
-
-    @pl.when(s == 0)
-    def _reset():
-        brow_ref[...] = jnp.ones_like(brow_ref)
-        if brow2_ref is not None:
-            brow2_ref[...] = jnp.ones_like(brow2_ref)
-
-    if save_cps:
-        cps_ref[0, 0, :] = brow_ref[0, :]
-        if brow2_ref is not None:
-            cps_ref[0, 1, :] = brow2_ref[0, :]
-
-    _wavefront(delta_ref[0], out_ref, cps_ref, brow_ref, brow2_ref, T=T,
-               lam1=lam1, lam2=lam2, ny=ny, save_cps=save_cps, scheme=scheme,
-               interior_dtype=interior_dtype)
-
-
-def _wavefront(blk, out_ref, cps_ref, brow_ref, brow2_ref=None, *, T, lam1,
-               lam2, ny, save_cps, scheme="order1",
-               interior_dtype="float32"):
-    """Anti-diagonal sweep of one strip given its unrefined Δ block (R, Ly)."""
-    M = _expand_dyadic(blk, lam1, lam2)                # (T, ny)
-    S_T = skew_to_ST(M, T, ny)                         # (ny+T, T): [t, r] = Δ(r, t-r)
-
+    W = s_ref.shape[0]
     order2 = scheme == "order2"
+    m1, m2 = (1 << lam1) - 1, (1 << lam2) - 1
     lane = jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
 
+    def above(i):
+        # lane T−1 of row i is k̂[strip_top, i − T + 2] and lane T−2 is
+        # k̂[strip_top − 1, i − T + 3]; rows past the end only feed
+        # inactive lanes
+        return src_ref[pl.ds(jnp.minimum(i, W - 1), 1), :]
+
     def step(t, carry):
-        prev, prev2 = carry                            # (1, T) f32
-        p = jax.lax.dynamic_slice(S_T, (t, 0), (1, T))  # anti-diagonal of Δ
-        A = coeff_A(p)
-        up0 = brow_ref[0, t + 1]
-        upleft0 = brow_ref[0, t]
-        shift_prev = jnp.where(lane == 0, up0, jnp.roll(prev, 1, axis=1))
-        shift_prev2 = jnp.where(lane == 0, upleft0, jnp.roll(prev2, 1, axis=1))
+        prev, prev2, above_prev = carry                # (1, T) f32
+        above_t = above(t + T - 1)
+        p = s_ref[pl.ds(t, 1), :]                      # anti-diagonal of Δ
+        shift_prev = jnp.where(lane == 0, roll(above_t, 1), roll(prev, 1))
+        shift_prev2 = jnp.where(lane == 0, roll(above_prev, 1),
+                                roll(prev2, 1))
         left = jnp.where(lane == t, 1.0, prev)
         upleft = jnp.where(lane == t, 1.0, shift_prev2)
         if order2:
             # Skew neighbours both sit two wavefront steps back (prev2):
             # k_dl = k̂[i+1, c−1] is prev2 at the SAME lane (:= 1 for c ≤ 1 —
             # the boundary of ones extends); k_ul = k̂[i−1, c+1] is prev2 two
-            # lanes up, with lanes 1/0 reading the carried boundary rows
-            # (brow[t] = k̂[strip_top, t], brow2[t+1] = k̂[strip_top−1, t+1]).
+            # lanes up, lanes 1/0 reading lanes T−1/T−2 of the strip above.
             # Data-gridline fallback (stencil.py): global row = strip·T +
-            # lane and T ≡ 0 (mod 2^λ1), so the row test is lane % 2^λ1;
+            # lane and T ≡ 0 (mod 2^λ1), so the row test is lane mod 2^λ1;
             # the column is c = t − lane.
-            edge = (lane % (1 << lam1) == 0) | ((t - lane) % (1 << lam2) == 0)
+            edge = ((lane & m1) == 0) | (((t - lane) & m2) == 0)
             k_dl = jnp.where(lane >= t - 1, 1.0, prev2)
-            k_ul = jnp.roll(prev2, 2, axis=1)
-            k_ul = jnp.where(lane == 1, brow_ref[0, t], k_ul)
-            k_ul = jnp.where(lane == 0, brow2_ref[0, t + 1], k_ul)
-            cur = ((left + shift_prev) * A
+            k_ul = jnp.where(lane < 2, roll(above_prev, 2), roll(prev2, 2))
+            cur = ((left + shift_prev) * stencil.coeff_A(p)
                    - upleft * stencil.coeff_B2_at(p, edge)
                    - (k_dl + k_ul) * stencil.coeff_C2_at(p, edge))
         else:
-            cur = (left + shift_prev) * A - upleft * coeff_B(p)
+            cur = ((left + shift_prev) * stencil.coeff_A(p)
+                   - upleft * stencil.coeff_B1(p))
         cur = stencil.round_interior(cur, interior_dtype)
         active = (lane <= t) & (lane > t - ny)
         cur = jnp.where(active, cur, 0.0)
+        dst_ref[pl.ds(t, 1), :] = cur
+        return (cur, prev, above_t)
 
-        # bottom strip row becomes next strip's boundary: in-place overwrite,
-        # reads (index t+1) trail writes (index t-T+2) by T-1 steps.
-        @pl.when(t >= T - 1)
-        def _():
-            brow_ref[0, t - T + 2] = cur[0, T - 1]
+    zeros = zeros_row(s_ref)
+    last, _, _ = jax.lax.fori_loop(0, ny + T - 1, step,
+                                   (zeros, zeros, above(max(T - 2, 0))))
+    return last
 
-        if order2:
-            # row T−2 becomes next strip's brow2 (k̂[next_top − 1, ·]); the
-            # lane-0 read (index t+1) never trails this write for T ≥ 2.
-            @pl.when(t >= T - 2)
-            def _():
-                brow2_ref[0, t - T + 3] = cur[0, T - 2]
 
-        return (cur, prev)
+def zeros_row(ref) -> jax.Array:
+    """A (1, T) row of zeros computed from row 0 of ``ref`` (finite).
 
-    zeros = jnp.zeros((1, T), jnp.float32)
-    jax.lax.fori_loop(0, ny + T - 1, step, (zeros, zeros))
+    Mosaic lays a loop carry out like its initial value; a constant's
+    replicated layout cannot take the computed rows the loop yields.
+    """
+    return ref[pl.ds(0, 1), :] * 0.0 + 0.0
 
-    # after the strip, brow[ny] = k̂[strip_bottom, ny]; last strip ⇒ k̂[nx, ny].
-    if out_ref is not None:
-        out_ref[0] = brow_ref[0, ny]
 
+def place(row: jax.Array, idx, last: jax.Array) -> jax.Array:
+    """Lane-dense output row with lane ``idx`` := lane T−1 of ``last``."""
+    T = last.shape[-1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, last.shape, 1)
+    k = jnp.sum(jnp.where(lane == T - 1, last, 0.0), axis=1, keepdims=True)
+    pos = jax.lax.broadcasted_iota(jnp.int32, row.shape, row.ndim - 1)
+    return jnp.where(pos == idx, k, row)
+
+
+def _strip(M, s_ref, rows_ref, cps_ref, *, strip_axis, **kw):
+    """Shared strip body: reset at the first strip, checkpoint, skew, sweep."""
+    @pl.when(pl.program_id(strip_axis) == 0)
+    def _reset():
+        rows_ref[...] = jnp.ones_like(rows_ref)
+
+    if cps_ref is not None:
+        CR = cps_ref.shape[2]
+        cps_ref[0, 0] = rows_ref[...].T[-CR:]
+    s_ref[...] = skew(M)
+    return sweep(s_ref, rows_ref, rows_ref, **kw)
+
+
+def fwd_kernel(delta_ref, out_ref, *refs, T: int, lam1: int, lam2: int,
+               ny: int, save_cps: bool, scheme: str = "order1",
+               interior_dtype: str = "float32"):
+    """One (batch, strip) grid step of the forward wavefront solver.
+
+    delta_ref: (1, R, Ly) unrefined Δ rows of this strip (VMEM block).
+    out_ref:   (1, batch) resident row of final kernel values k̂[nx, ny];
+               lane b is rewritten every strip, the last strip's write is
+               the result.
+    cps_ref:   (1, 1, CR, W) checkpoint of this strip (grad mode only).
+    s_ref, rows_ref: (W, T) scratch — skewed Δ, and the wavefront rows
+               carried from strip to strip.
+    """
+    cps_ref, s_ref, rows_ref = refs if save_cps else (None, *refs)
+    M = refine(delta_ref[0], T, s_ref.shape[0], lam1, lam2)
+    last = _strip(M, s_ref, rows_ref, cps_ref, strip_axis=1, T=T, lam1=lam1,
+                  lam2=lam2, ny=ny, scheme=scheme,
+                  interior_dtype=interior_dtype)
+    out_ref[...] = place(out_ref[...], pl.program_id(0), last)
+
+
+def fused_fwd_kernel(dx_ref, dy_ref, out_ref, s_ref, rows_ref, *, T: int,
+                     lam1: int, lam2: int, ny: int, scheme: str = "order1",
+                     interior_dtype: str = "float32"):
+    """Fused-Δ forward: the strip's Δ block is computed in VMEM from the
+    increments (an (R, d) × (d, Ly) MXU matmul) — Δ never exists in HBM.
+
+    Beyond-paper variant: pySigLib precomputes Δ with one bmm (design
+    choice (2)).  Whether recomputing Δ beats streaming it from HBM on a
+    TPU is not measured.
+    """
+    M = refine_fused(dx_ref[0], dy_ref[0], T, s_ref.shape[0], lam1, lam2)
+    last = _strip(M, s_ref, rows_ref, None, strip_axis=1, T=T, lam1=lam1,
+                  lam2=lam2, ny=ny, scheme=scheme,
+                  interior_dtype=interior_dtype)
+    out_ref[...] = place(out_ref[...], pl.program_id(0), last)
+
+
+def fused_gram_kernel(dx_ref, dy_ref, out_ref, s_ref, rows_ref, *, T: int,
+                      lam1: int, lam2: int, ny: int, scheme: str = "order1",
+                      interior_dtype: str = "float32"):
+    """Fused-Δ Gram: program (a, b, strip) solves pair (x_a, y_b); out_ref
+    is the resident (1, 1, By) Gram row a."""
+    M = refine_fused(dx_ref[0], dy_ref[0], T, s_ref.shape[0], lam1, lam2)
+    last = _strip(M, s_ref, rows_ref, None, strip_axis=2, T=T, lam1=lam1,
+                  lam2=lam2, ny=ny, scheme=scheme,
+                  interior_dtype=interior_dtype)
+    out_ref[0] = place(out_ref[0], pl.program_id(1), last)
 
 
 def check_strip(T: int, lam1: int, Lx: int, scheme: str = "order1") -> int:
@@ -220,7 +304,7 @@ def check_strip(T: int, lam1: int, Lx: int, scheme: str = "order1") -> int:
             f"Goursat strip height T={T} must be a power-of-two multiple of "
             f"the dyadic refinement 2**lam1={1 << lam1} — raise "
             f"LaunchConfig.pde_strip (or lower lam1); the default cap is "
-            f"{128}")
+            f"{LANES}")
     if Lx % R != 0:
         raise ValueError(
             f"Lx={Lx} rows are not a multiple of the R={R} unrefined rows "
@@ -235,23 +319,11 @@ def check_strip(T: int, lam1: int, Lx: int, scheme: str = "order1") -> int:
     return R
 
 
-def _scratch_rows(ny: int, T: int, scheme: str):
-    """Carried-boundary scratch: one row for order-1, two for order-2."""
-    rows = [vmem_scratch((1, ny + T + 1))]
-    if scheme == "order2":
-        rows.append(vmem_scratch((1, ny + T + 1)))
-    return rows
-
-
-def cps_rows(scheme: str) -> int:
-    """Checkpoint rows per strip (brow, plus brow2 for the order-2 stencil)."""
-    return 2 if scheme == "order2" else 1
-
-
 def build_fwd(batch: int, Lx: int, Ly: int, *, T: int, lam1: int, lam2: int,
               save_cps: bool, interpret: bool, scheme: str = "order1",
               interior_dtype: str = "float32"):
-    """Construct the pallas_call for the forward solver.
+    """Forward solver: returns ``f(delta (batch, Lx, Ly)) -> k (batch,)``,
+    or ``(k, cps)`` with ``save_cps``.
 
     Lx must be a multiple of R = T >> lam1 (ops.py zero-pads: Δ = 0 rows/cols
     leave the Goursat solution invariant since A(0) = B(0) = 1; the order-2
@@ -260,110 +332,89 @@ def build_fwd(batch: int, Lx: int, Ly: int, *, T: int, lam1: int, lam2: int,
     R = check_strip(T, lam1, Lx, scheme)
     n_strips = Lx // R
     ny = Ly << lam2
-    rows = cps_rows(scheme)
-
+    W = strip_width(ny, T)
+    kern = functools.partial(fwd_kernel, T=T, lam1=lam1, lam2=lam2, ny=ny,
+                             save_cps=save_cps, scheme=scheme,
+                             interior_dtype=interior_dtype)
+    out_shape = [jax.ShapeDtypeStruct((1, batch), jnp.float32)]
+    out_specs = [pl.BlockSpec((1, batch), lambda b, s: (0, 0))]
     if save_cps:
-        kern = functools.partial(fwd_kernel, T=T, lam1=lam1, lam2=lam2, ny=ny,
-                                 save_cps=True, scheme=scheme,
-                                 interior_dtype=interior_dtype)
-    elif scheme == "order2":
-        def kern(delta_ref, out_ref, brow_ref, brow2_ref):
-            fwd_kernel(delta_ref, out_ref, None, brow_ref, brow2_ref,
-                       T=T, lam1=lam1, lam2=lam2, ny=ny, save_cps=False,
-                       scheme=scheme, interior_dtype=interior_dtype)
-    else:
-        def kern(delta_ref, out_ref, brow_ref):
-            fwd_kernel(delta_ref, out_ref, None, brow_ref,
-                       T=T, lam1=lam1, lam2=lam2, ny=ny, save_cps=False,
-                       scheme=scheme, interior_dtype=interior_dtype)
-
-    out_shapes = [jax.ShapeDtypeStruct((batch,), jnp.float32)]
-    out_specs = [pl.BlockSpec((1,), lambda b, s: (b,))]
-    if save_cps:
-        # rows checkpoint rows per strip, folded into one axis so the order-1
-        # layout (rows = 1) stays bitwise-identical to the historical one.
-        out_shapes.append(jax.ShapeDtypeStruct(
-            (batch, n_strips * rows, ny + T + 1), jnp.float32))
-        out_specs.append(
-            pl.BlockSpec((1, rows, ny + T + 1), lambda b, s: (b, s, 0)))
-
-    return pl.pallas_call(
+        CR = cps_lanes(T)
+        out_shape.append(jax.ShapeDtypeStruct((batch, n_strips, CR, W),
+                                              jnp.float32))
+        out_specs.append(pl.BlockSpec((1, 1, CR, W),
+                                      lambda b, s: (b, s, 0, 0)))
+    call = pl.pallas_call(
         kern,
         grid=(batch, n_strips),
         in_specs=[pl.BlockSpec((1, R, Ly), lambda b, s: (b, s, 0))],
-        out_specs=out_specs if save_cps else out_specs[0],
-        out_shape=out_shapes if save_cps else out_shapes[0],
-        scratch_shapes=_scratch_rows(ny, T, scheme),
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=[vmem_scratch((W, T)), vmem_scratch((W, T))],
+        compiler_params=compiler_params(W, T, 2),
         interpret=interpret,
     )
+
+    def run(delta):
+        k, *cps = call(delta)
+        return (k[0], *cps) if save_cps else k[0]
+
+    return run
 
 
 def build_fwd_fused(batch: int, Lx: int, Ly: int, d: int, *, T: int,
                     lam1: int, lam2: int, interpret: bool,
                     scheme: str = "order1", interior_dtype: str = "float32"):
-    """Fused-Δ forward: inputs are increments dx (B, Lx, d), dy (B, Ly, d)."""
+    """Fused-Δ forward: ``f(dx (B, Lx, d), dy (B, Ly, d)) -> k (B,)``."""
     R = check_strip(T, lam1, Lx, scheme)
     n_strips = Lx // R
     ny = Ly << lam2
+    W = strip_width(ny, T)
     kern = functools.partial(fused_fwd_kernel, T=T, lam1=lam1, lam2=lam2,
                              ny=ny, scheme=scheme,
                              interior_dtype=interior_dtype)
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kern,
         grid=(batch, n_strips),
         in_specs=[pl.BlockSpec((1, R, d), lambda b, s: (b, s, 0)),
                   pl.BlockSpec((1, Ly, d), lambda b, s: (b, 0, 0))],
-        out_specs=pl.BlockSpec((1,), lambda b, s: (b,)),
-        out_shape=jax.ShapeDtypeStruct((batch,), jnp.float32),
-        scratch_shapes=_scratch_rows(ny, T, scheme),
+        out_specs=pl.BlockSpec((1, batch), lambda b, s: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((1, batch), jnp.float32),
+        scratch_shapes=[vmem_scratch((W, T)), vmem_scratch((W, T))],
+        compiler_params=compiler_params(W, T, 2),
         interpret=interpret,
     )
-
-
-def fused_gram_kernel(dx_ref, dy_ref, out_ref, brow_ref, brow2_ref=None, *,
-                      T: int, lam1: int, lam2: int, ny: int,
-                      scheme: str = "order1", interior_dtype: str = "float32"):
-    s = pl.program_id(2)
-
-    @pl.when(s == 0)
-    def _reset():
-        brow_ref[...] = jnp.ones_like(brow_ref)
-        if brow2_ref is not None:
-            brow2_ref[...] = jnp.ones_like(brow2_ref)
-
-    blk = jnp.dot(dx_ref[0], dy_ref[0].T,
-                  preferred_element_type=jnp.float32)
-    _wavefront(blk, None, None, brow_ref, brow2_ref, T=T, lam1=lam1,
-               lam2=lam2, ny=ny, save_cps=False, scheme=scheme,
-               interior_dtype=interior_dtype)
-    out_ref[0, 0] = brow_ref[0, ny]
+    return lambda dx, dy: call(dx, dy)[0]
 
 
 def build_gram_fused(Bx: int, By: int, Lx: int, Ly: int, d: int, *, T: int,
                      lam1: int, lam2: int, interpret: bool,
                      scheme: str = "order1", interior_dtype: str = "float32"):
-    """Fused-Δ Gram: grid over (row path, col path, strip); dx/dy blocks are
-    fetched from the ORIGINAL increment arrays by index map — neither Δ nor
-    any pairwise replication of the paths ever exists in HBM."""
+    """Fused-Δ Gram ``f(dX (Bx, Lx, d), dY (By, Ly, d)) -> (Bx, By)``: grid
+    over (row path, col path, strip); dx/dy blocks are fetched from the
+    ORIGINAL increment arrays by index map — neither Δ nor any pairwise
+    replication of the paths ever exists in HBM."""
     R = check_strip(T, lam1, Lx, scheme)
     n_strips = Lx // R
     ny = Ly << lam2
+    W = strip_width(ny, T)
     kern = functools.partial(fused_gram_kernel, T=T, lam1=lam1, lam2=lam2,
                              ny=ny, scheme=scheme,
                              interior_dtype=interior_dtype)
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kern,
         grid=(Bx, By, n_strips),
         in_specs=[pl.BlockSpec((1, R, d), lambda a, b, s: (a, s, 0)),
                   pl.BlockSpec((1, Ly, d), lambda a, b, s: (b, 0, 0))],
-        out_specs=pl.BlockSpec((1, 1), lambda a, b, s: (a, b)),
-        out_shape=jax.ShapeDtypeStruct((Bx, By), jnp.float32),
-        scratch_shapes=_scratch_rows(ny, T, scheme),
+        out_specs=pl.BlockSpec((1, 1, By), lambda a, b, s: (a, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((Bx, 1, By), jnp.float32),
+        scratch_shapes=[vmem_scratch((W, T)), vmem_scratch((W, T))],
+        compiler_params=compiler_params(W, T, 2),
         interpret=interpret,
     )
+    return lambda dX, dY: call(dX, dY)[:, 0, :]
 
 
 def vmem_scratch(shape, dtype=jnp.float32):
     """VMEM scratch allocator (TPU target; also honoured by interpret mode)."""
-    from jax.experimental.pallas import tpu as pltpu
     return pltpu.VMEM(shape, dtype)

@@ -7,8 +7,8 @@ Responsibilities:
   solution invariant because A(0) = B(0) = 1, so padding is exact — and the
   padded problem's *exact* adjoint restricted to the real Δ block is the real
   problem's exact adjoint),
-* strip-height (T) selection under the VMEM budget,
-* interpret-mode selection (CPU: interpret=True; TPU: compiled).
+* strip-height (T) selection,
+* interpret-mode selection (TPU: compiled; elsewhere interpret=True).
 """
 
 from __future__ import annotations
@@ -18,32 +18,20 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from .. import interpret_mode
 from .kernel import build_fwd
 from .grad_kernel import build_bwd
 
-# ~12 MiB working-set budget out of ~16 MiB VMEM per core
-_VMEM_BUDGET = 12 * 1024 * 1024
 _MAX_T = 128
 
 
-def _on_cpu() -> bool:
-    return jax.default_backend() == "cpu"
+def choose_T(lam1: int, max_t: int = _MAX_T) -> int:
+    """Strip height: the cap (one strip row per lane), at least 2**lam1.
 
-
-def choose_T(Lx: int, Ly: int, lam1: int, lam2: int,
-             max_t: int = _MAX_T) -> int:
-    """Largest power-of-two strip height ≤ ``max_t`` whose VMEM working set
-    fits."""
-    ny = Ly << lam2
-    T = max_t
-    while T > (1 << lam1):
-        R = T >> lam1
-        # Δ block + expanded M + skewed S_T (+ ~3x for bwd scratch)
-        working = 4 * (R * Ly + T * ny + (ny + T) * T * 4)
-        if working <= _VMEM_BUDGET:
-            break
-        T //= 2
-    return max(T, 1 << lam1)
+    The VMEM working set is a few (W, 128)-padded buffers whatever T ≤ 128
+    is, so a lower strip saves nothing; ``kernel.vmem_limit`` sizes it.
+    """
+    return max(max_t, 1 << lam1)
 
 
 def _pad_batched(delta: jax.Array, R: int):
@@ -64,10 +52,10 @@ def _solve_flat(delta: jax.Array, lam1: int, lam2: int, with_cps: bool,
                 launch=None, scheme: str = "order1",
                 interior_dtype: str = "float32"):
     B, Lx, Ly = delta.shape
-    T = choose_T(Lx, Ly, lam1, lam2, max_t=_max_t(launch))
+    T = choose_T(lam1, _max_t(launch))
     delta, Lxp = _pad_batched(delta, T >> lam1)
     call = build_fwd(B, Lxp, Ly, T=T, lam1=lam1, lam2=lam2,
-                     save_cps=with_cps, interpret=_on_cpu(), scheme=scheme,
+                     save_cps=with_cps, interpret=interpret_mode(), scheme=scheme,
                      interior_dtype=interior_dtype)
     out = call(delta)
     return out
@@ -99,10 +87,10 @@ def solve_with_grid(delta: jax.Array, lam1: int = 0, lam2: int = 0,
 def _grad_flat(delta, cps, gbar, lam1, lam2, launch=None,
                scheme: str = "order1", interior_dtype: str = "float32"):
     B, Lx, Ly = delta.shape
-    T = choose_T(Lx, Ly, lam1, lam2, max_t=_max_t(launch))
+    T = choose_T(lam1, _max_t(launch))
     delta, Lxp = _pad_batched(delta, T >> lam1)
     call = build_bwd(B, Lxp, Ly, T=T, lam1=lam1, lam2=lam2,
-                     interpret=_on_cpu(), scheme=scheme,
+                     interpret=interpret_mode(), scheme=scheme,
                      interior_dtype=interior_dtype)
     dd = call(delta, delta, cps, gbar)
     return dd[:, :Lx, :]
@@ -142,13 +130,13 @@ def _solve_fused_impl(dx: jax.Array, dy: jax.Array, lam1: int,
     from .kernel import build_fwd_fused
     B, Lx, d = dx.shape
     Ly = dy.shape[1]
-    T = choose_T(Lx, Ly, lam1, lam2, max_t=_max_t(launch))
+    T = choose_T(lam1, _max_t(launch))
     R = T >> lam1
     pad = (-Lx) % R
     if pad:  # zero increments -> zero Δ rows -> exact no-ops
         dx = jnp.pad(dx, ((0, 0), (0, pad), (0, 0)))
     call = build_fwd_fused(B, Lx + pad, Ly, d, T=T, lam1=lam1, lam2=lam2,
-                           interpret=_on_cpu(), scheme=scheme,
+                           interpret=interpret_mode(), scheme=scheme,
                            interior_dtype=interior_dtype)
     return call(dx.astype(jnp.float32), dy.astype(jnp.float32))
 
@@ -196,13 +184,13 @@ def _gram_fused_impl(dX: jax.Array, dY: jax.Array, lam1: int,
     from .kernel import build_gram_fused
     Bx, Lx, d = dX.shape
     By, Ly = dY.shape[0], dY.shape[1]
-    T = choose_T(Lx, Ly, lam1, lam2, max_t=_max_t(launch))
+    T = choose_T(lam1, _max_t(launch))
     R = T >> lam1
     pad = (-Lx) % R
     if pad:
         dX = jnp.pad(dX, ((0, 0), (0, pad), (0, 0)))
     call = build_gram_fused(Bx, By, Lx + pad, Ly, d, T=T, lam1=lam1,
-                            lam2=lam2, interpret=_on_cpu(), scheme=scheme,
+                            lam2=lam2, interpret=interpret_mode(), scheme=scheme,
                             interior_dtype=interior_dtype)
     return call(dX.astype(jnp.float32), dY.astype(jnp.float32))
 

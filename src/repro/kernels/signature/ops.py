@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.tensoralg import sig_dim, level_sizes
+from .. import interpret_mode
 from .kernel import build_horner
 
 _VMEM_BUDGET = 10 * 1024 * 1024
@@ -23,10 +24,10 @@ _LB = 256
 
 
 def default_use_pallas() -> bool:
-    """Backend-based default for ``use_pallas=None``: the compiled kernel is
-    the fast path on TPU; elsewhere it runs in interpret mode, so the pure-JAX
+    """Backend-based default for ``use_pallas=None``: the compiled kernel on
+    TPU; elsewhere it would run in interpret mode, so the pure-JAX
     implementation is preferred."""
-    return jax.default_backend() == "tpu"
+    return not interpret_mode()
 
 
 def choose_BT(d: int, depth: int, LB: int, max_bt: int = _MAX_BT) -> int:
@@ -54,7 +55,7 @@ def _horner_flat(z: jax.Array, depth: int, launch=None) -> jax.Array:
     n_tiles = Bp // BT
     zt = zp.reshape(n_tiles, BT, Lp, d).transpose(0, 2, 3, 1)  # (t, L, d, BT)
     out = build_horner(n_tiles, Lp, d, depth, BT=BT, LB=LB,
-                       interpret=jax.default_backend() == "cpu")(zt)
+                       interpret=interpret_mode())(zt)
     sd = sig_dim(d, depth)
     return out.transpose(0, 2, 1).reshape(Bp, sd)[:B]
 

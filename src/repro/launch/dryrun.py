@@ -43,7 +43,7 @@ def build_sig_cell(shape, multi_pod: bool):
     over model — the Gram tiling from DESIGN.md §6."""
     import functools
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from repro.core.gram import sigkernel_gram
     from repro.configs.sigkernel_workload import GRAM_ENGINE_DEFAULTS
 

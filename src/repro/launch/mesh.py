@@ -7,9 +7,10 @@ The simulated-mesh helpers (:func:`host_device_flags`,
 :func:`simulated_mesh_env`) exist because XLA's host-platform device count
 is fixed at backend initialisation: a process that wants N fake CPU devices
 must set ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` *before*
-jax initialises.  Tests and benches therefore spawn subprocesses with the
-env these helpers build (see ``tests/conftest.py`` — the ``simulated_mesh``
-fixture — and the ``multidevice`` CI job).
+jax initialises.  The multidevice tests therefore spawn CPU subprocesses
+with the env these helpers build (see ``tests/conftest.py`` — the
+``simulated_mesh`` fixture — and the ``multidevice`` CI job).  Nothing that
+may hold a TPU starts such a child: the chip belongs to one process.
 """
 
 from __future__ import annotations

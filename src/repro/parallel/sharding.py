@@ -21,18 +21,8 @@ from .api import DEFAULT_RULES, MULTIPOD_RULES, Axis
 
 
 def get_shard_map():
-    """The ``shard_map`` transform across supported jax versions.
-
-    Newer jax exposes :func:`jax.shard_map`; older releases only have
-    ``jax.experimental.shard_map.shard_map``.  Import at call time so
-    importing this module never drags in experimental namespaces.
-    """
-    try:
-        from jax import shard_map  # jax >= 0.6
-        return shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-        return shard_map
+    """The ``shard_map`` transform (:func:`jax.shard_map`)."""
+    return jax.shard_map
 
 
 def block_cyclic_perm(n: int, n_shards: int, block: int):

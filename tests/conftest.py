@@ -64,3 +64,17 @@ def simulated_mesh():
         return r.stdout
 
     return run
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _release_compiled_programs():
+    """Drop JAX's compiled programs after each test module.
+
+    XLA's CPU backend maps the code of every compiled program into the
+    process; a test worker that runs several modules full of interpret-mode
+    Pallas compiles would otherwise pass the kernel's memory-map limit
+    (``vm.max_map_count``) and abort.
+    """
+    yield
+    import jax
+    jax.clear_caches()

@@ -240,9 +240,8 @@ def test_gram_grad_matches_truncated_oracle_autodiff():
 def test_gram_grad_matches_finite_differences_x64():
     """FD gradcheck through lengths= with time-aug + lead-lag + basepoint
     (f64 so the FD quotient is meaningful)."""
-    from jax.experimental import enable_x64
     cfg = PIPELINES["all"]
-    with enable_x64():
+    with jax.enable_x64(True):
         x = jnp.asarray(np.asarray(X[:2, :6], np.float64))
         y = jnp.asarray(np.asarray(Y[:2, :7], np.float64))
         lens = jnp.asarray([4, 6])

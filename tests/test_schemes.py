@@ -128,7 +128,7 @@ def test_backward_exact_per_combination(backend, scheme, idt):
 def test_backward_matches_finite_differences(scheme):
     """f64 central differences against the one-pass adjoint — the
     discretisation-independent ground truth for the custom VJP."""
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         key = jax.random.PRNGKey(6)
         d = (jax.random.normal(key, (4, 5)) * 0.3).astype(jnp.float64)
         v = jax.random.normal(jax.random.PRNGKey(7), (4, 5)).astype(
@@ -152,7 +152,7 @@ def test_backward_matches_finite_differences(scheme):
 # ---------------------------------------------------------------------------
 
 def test_order2_accuracy_gates():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         x = (jax.random.normal(jax.random.PRNGKey(0), (2, 5, 2))
              ).astype(jnp.float64)
         y = (jax.random.normal(jax.random.PRNGKey(1), (2, 5, 2))
