@@ -76,16 +76,31 @@ def _prepare(paths: jax.Array, cfg, kernel, lengths=None) -> jax.Array:
     the fused kernels, the symmetric fast path, the sharded tiling) is
     ragged-oblivious.
     """
-    if kernel.lifts_increments:
-        return tf.pipeline_increments(paths, cfg, lengths, align="end")
-    return tf.transform_path(paths, cfg, lengths, align="end")
+    with jax.named_scope("repro.transform"):
+        if kernel.lifts_increments:
+            return tf.pipeline_increments(paths, cfg, lengths, align="end")
+        return tf.transform_path(paths, cfg, lengths, align="end")
 
 
 def _pair_delta(sa: jax.Array, sb: jax.Array, kernel) -> jax.Array:
     """Δ for batches of prepared streams (leading dims broadcast)."""
-    if kernel.lifts_increments:
-        return kernel.delta_from_increments(sa, sb)
-    return delta_from_gram(kernel.gram(sa, sb))
+    with jax.named_scope("repro.gram.pairs"):
+        if kernel.lifts_increments:
+            return kernel.delta_from_increments(sa, sb)
+        return delta_from_gram(kernel.gram(sa, sb))
+
+
+def _gather_pairs(sX: jax.Array, a_idx, b_idx):
+    """The prepared streams of the pairs ``(a_idx[i], b_idx[i])``."""
+    with jax.named_scope("repro.gram.pairs"):
+        return sX[a_idx], sX[b_idx]
+
+
+def _mirror(k: jax.Array, a_idx, b_idx, B: int) -> jax.Array:
+    """The symmetric (B, B) Gram from its upper-triangle values ``k``."""
+    with jax.named_scope("repro.gram.reduce"):
+        K = jnp.zeros((B, B), k.dtype).at[a_idx, b_idx].set(k)
+        return K + jnp.triu(K, k=1).T
 
 
 def _solve_pairs(sa: jax.Array, sb: jax.Array, kernel, backend: str,
@@ -127,10 +142,11 @@ def _gram_rows(sX: jax.Array, sY: jax.Array, kernel, backend: str,
     if row_block is None:
         return _gram_block(sX, sY, kernel, backend, g, launch)
     pad = (-Bx) % row_block
-    if pad:  # zero rows -> Δ = 0 -> k = 1 rows, dropped below: exact
-        sX = jnp.pad(sX, ((0, pad), (0, 0), (0, 0)))
     n_blocks = (Bx + pad) // row_block
-    sXb = sX.reshape(n_blocks, row_block, *sX.shape[1:])
+    with jax.named_scope("repro.gram.pairs"):
+        if pad:  # zero rows -> Δ = 0 -> k = 1 rows, dropped below: exact
+            sX = jnp.pad(sX, ((0, pad), (0, 0), (0, 0)))
+        sXb = sX.reshape(n_blocks, row_block, *sX.shape[1:])
     K = jax.lax.map(
         lambda sxb: _gram_block(sxb, sY, kernel, backend, g, launch),
         sXb)
@@ -151,12 +167,14 @@ def _solve_pairs_chunked(sX: jax.Array, a_idx, b_idx, kernel, backend: str,
     a_idx, b_idx = jnp.asarray(a_idx), jnp.asarray(b_idx)
     n = a_idx.shape[0]
     if chunk is None or chunk >= n:
-        return _solve_pairs(sX[a_idx], sX[b_idx], kernel, backend, g, launch)
+        return _solve_pairs(*_gather_pairs(sX, a_idx, b_idx), kernel,
+                            backend, g, launch)
     pad = (-n) % chunk
-    a = jnp.concatenate([a_idx, jnp.zeros((pad,), a_idx.dtype)])
-    b = jnp.concatenate([b_idx, jnp.zeros((pad,), b_idx.dtype)])
+    with jax.named_scope("repro.gram.pairs"):
+        a = jnp.concatenate([a_idx, jnp.zeros((pad,), a_idx.dtype)])
+        b = jnp.concatenate([b_idx, jnp.zeros((pad,), b_idx.dtype)])
     k = jax.lax.map(
-        lambda ab: _solve_pairs(sX[ab[0]], sX[ab[1]], kernel, backend, g,
+        lambda ab: _solve_pairs(*_gather_pairs(sX, *ab), kernel, backend, g,
                                 launch),
         (a.reshape(-1, chunk), b.reshape(-1, chunk)))
     return k.reshape(-1)[:n]
@@ -508,17 +526,15 @@ def _symmetric_gram(sX: jax.Array, kernel, backend: str,
 
     if row_block is None:
         dispatch.record_pair_solves(n_pairs)
-        k = _solve_pairs(sX[a_idx], sX[b_idx], kernel, backend, g, launch)
+        k = _solve_pairs(*_gather_pairs(sX, a_idx, b_idx), kernel, backend,
+                         g, launch)
     else:
         # a block of `row_block` Gram rows ~ row_block·Bx pairs of live Δ
         chunk = max(1, int(row_block)) * Bx
         dispatch.record_pair_solves(n_pairs + (-n_pairs) % chunk)
         k = _solve_pairs_chunked(sX, a_idx, b_idx, kernel, backend, g,
                                  chunk, launch)
-
-    K = jnp.zeros((Bx, Bx), k.dtype).at[a_idx, b_idx].set(k)
-    K = K + jnp.triu(K, k=1).T
-    return shard(K, "batch", "model")
+    return shard(_mirror(k, a_idx, b_idx, Bx), "batch", "model")
 
 
 # ---------------------------------------------------------------------------
@@ -787,8 +803,10 @@ def _reduce_symmetric(sX: jax.Array, kernel, backend: str, row_block: int,
         chunk = Bx + 1
     if chunk >= n_pairs:
         dispatch.record_pair_solves(n_pairs)
-        k = _solve_pairs(sX[a_idx], sX[b_idx], kernel, backend, g, launch)
-        return (jnp.asarray(w, k.dtype) * k).sum()
+        k = _solve_pairs(*_gather_pairs(sX, a_idx, b_idx), kernel, backend,
+                         g, launch)
+        with jax.named_scope("repro.gram.reduce"):
+            return (jnp.asarray(w, k.dtype) * k).sum()
     pad = (-n_pairs) % chunk
     dispatch.record_pair_solves(n_pairs + pad)
     a = np.concatenate([a_idx, np.zeros(pad, a_idx.dtype)])
@@ -800,13 +818,16 @@ def _reduce_symmetric(sX: jax.Array, kernel, backend: str, row_block: int,
 
     def block(abw):
         ai, bi, wi = abw
-        k = _solve_pairs(sX[ai], sX[bi], kernel, backend, g, launch)
-        return (wi * k).sum()
+        k = _solve_pairs(*_gather_pairs(sX, ai, bi), kernel, backend, g,
+                         launch)
+        with jax.named_scope("repro.gram.reduce"):
+            return (wi * k).sum()
 
     # checkpoint: lax.map would otherwise stack every block's Δ/grid
     # residuals — the backward rematerialises them one block at a time
     parts = jax.lax.map(jax.checkpoint(block), (a_c, b_c, w_c))
-    return parts.sum()
+    with jax.named_scope("repro.gram.reduce"):
+        return parts.sum()
 
 
 def _reduce_rows(sX: jax.Array, sY: jax.Array, kernel, backend: str,
@@ -820,23 +841,28 @@ def _reduce_rows(sX: jax.Array, sY: jax.Array, kernel, backend: str,
         rb = 2
     if rb >= Bx:
         dispatch.record_pair_solves(Bx * By)
-        return _gram_block(sX, sY, kernel, backend, g, launch).sum()
+        K = _gram_block(sX, sY, kernel, backend, g, launch)
+        with jax.named_scope("repro.gram.reduce"):
+            return K.sum()
     pad = (-Bx) % rb
     n_blocks = (Bx + pad) // rb
     dispatch.record_pair_solves(n_blocks * rb * By)
-    if pad:
-        sX = jnp.pad(sX, ((0, pad), (0, 0), (0, 0)))
-    sXb = sX.reshape(n_blocks, rb, *sX.shape[1:])
+    with jax.named_scope("repro.gram.pairs"):
+        if pad:
+            sX = jnp.pad(sX, ((0, pad), (0, 0), (0, 0)))
+        sXb = sX.reshape(n_blocks, rb, *sX.shape[1:])
     # padded rows give k = 1 (zero increments), NOT 0 — mask them out
     valid = (jnp.arange(n_blocks * rb).reshape(n_blocks, rb) < Bx)
 
     def block(args):
         sxb, v = args
         Kb = _gram_block(sxb, sY, kernel, backend, g, launch)
-        return jnp.where(v[:, None], Kb, 0.0).sum()
+        with jax.named_scope("repro.gram.reduce"):
+            return jnp.where(v[:, None], Kb, 0.0).sum()
 
     parts = jax.lax.map(jax.checkpoint(block), (sXb, valid))
-    return parts.sum()
+    with jax.named_scope("repro.gram.reduce"):
+        return parts.sum()
 
 
 # ---------------------------------------------------------------------------
@@ -946,17 +972,16 @@ def sigkernel_gram_sharded(X: jax.Array, Y: Optional[jax.Array] = None, *,
                                      backend, g, chunk, launch)
             return k[None]
 
-        k_dev = shard_map(
-            local, mesh=mesh,
-            in_specs=(P((row_axis, col_axis)), P((row_axis, col_axis)),
-                      P()),
-            out_specs=P((row_axis, col_axis)), check_vma=False)(
-                a_dev, b_dev, sX)
-        # undo the deal: global pair t·D + r sits at device r, slot t
-        k = k_dev.reshape(D, n_loc).T.reshape(-1)[:n_pairs]
-        K = jnp.zeros((Bx, Bx), k.dtype).at[a_idx, b_idx].set(k)
-        K = K + jnp.triu(K, k=1).T
-        return shard(K, "batch", "model")
+        with jax.named_scope("repro.gram.shard"):
+            k_dev = shard_map(
+                local, mesh=mesh,
+                in_specs=(P((row_axis, col_axis)), P((row_axis, col_axis)),
+                          P()),
+                out_specs=P((row_axis, col_axis)), check_vma=False)(
+                    a_dev, b_dev, sX)
+            # undo the deal: global pair t·D + r sits at device r, slot t
+            k = k_dev.reshape(D, n_loc).T.reshape(-1)[:n_pairs]
+        return shard(_mirror(k, a_idx, b_idx, Bx), "batch", "model")
 
     sY = _prepare(Y, cfg, kernel, lengths_y)
     By = sY.shape[0]
@@ -968,10 +993,11 @@ def sigkernel_gram_sharded(X: jax.Array, Y: Optional[jax.Array] = None, *,
         n_blocks = -(-B // t)
         n_blocks += (-n_blocks) % n_shards
         padded = n_blocks * t
-        if padded > B:  # zero rows -> k = 1 tiles, sliced off at the end
-            s = jnp.pad(s, ((0, padded - B),) + ((0, 0),) * (s.ndim - 1))
         perm, inv = block_cyclic_perm(padded, n_shards, t)
-        return s[jnp.asarray(perm)], inv
+        with jax.named_scope("repro.gram.pairs"):
+            if padded > B:  # zero rows -> k = 1 tiles, sliced off at the end
+                s = jnp.pad(s, ((0, padded - B),) + ((0, 0),) * (s.ndim - 1))
+            return s[jnp.asarray(perm)], inv
 
     sXp, invR = _deal(sX, nd)
     sYp, invC = _deal(sY, nm)
@@ -980,8 +1006,10 @@ def sigkernel_gram_sharded(X: jax.Array, Y: Optional[jax.Array] = None, *,
     def local(sx, sy):
         return _gram_rows(sx, sy, kernel, backend, g, row_block, launch)
 
-    Kp = shard_map(local, mesh=mesh,
-                   in_specs=(P(row_axis), P(col_axis)),
-                   out_specs=P(row_axis, col_axis), check_vma=False)(sXp, sYp)
-    K = Kp[jnp.asarray(invR)][:, jnp.asarray(invC)][:Bx, :By]
+    with jax.named_scope("repro.gram.shard"):
+        Kp = shard_map(local, mesh=mesh,
+                       in_specs=(P(row_axis), P(col_axis)),
+                       out_specs=P(row_axis, col_axis),
+                       check_vma=False)(sXp, sYp)
+        K = Kp[jnp.asarray(invR)][:, jnp.asarray(invC)][:Bx, :By]
     return shard(K, "batch", "model")

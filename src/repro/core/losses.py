@@ -104,23 +104,25 @@ def mmd2(X: jax.Array, Y: jax.Array, *, transforms=None, grid=None,
                                         include_diag=not unbiased, **rkw)
         sxy_sum = sigkernel_gram_reduce(X, Y, lengths=lengths,
                                         lengths_y=lengths_y, **rkw)
-        if unbiased:
-            sxx = sxx_sum / (bx * (bx - 1))
-            syy = syy_sum / (by * (by - 1))
-        else:
-            sxx = sxx_sum / (bx * bx)
-            syy = syy_sum / (by * by)
-        return sxx + syy - 2.0 * sxy_sum / (bx * by)
+        with jax.named_scope("repro.gram.reduce"):
+            if unbiased:
+                sxx = sxx_sum / (bx * (bx - 1))
+                syy = syy_sum / (by * (by - 1))
+            else:
+                sxx = sxx_sum / (bx * bx)
+                syy = syy_sum / (by * by)
+            return sxx + syy - 2.0 * sxy_sum / (bx * by)
     Kxx = sigkernel_gram(X, lengths=lengths, **kw)   # upper triangle only
     Kyy = sigkernel_gram(Y, lengths=lengths_y, **kw)
     Kxy = sigkernel_gram(X, Y, lengths=lengths, lengths_y=lengths_y, **kw)
-    if unbiased:
-        sxx = (Kxx.sum() - jnp.trace(Kxx)) / (bx * (bx - 1))
-        syy = (Kyy.sum() - jnp.trace(Kyy)) / (by * (by - 1))
-    else:
-        sxx = Kxx.mean()
-        syy = Kyy.mean()
-    return sxx + syy - 2.0 * Kxy.mean()
+    with jax.named_scope("repro.gram.reduce"):
+        if unbiased:
+            sxx = (Kxx.sum() - jnp.trace(Kxx)) / (bx * (bx - 1))
+            syy = (Kyy.sum() - jnp.trace(Kyy)) / (by * (by - 1))
+        else:
+            sxx = Kxx.mean()
+            syy = Kyy.mean()
+        return sxx + syy - 2.0 * Kxy.mean()
 
 
 def scoring_rule(X: jax.Array, y: jax.Array, *, transforms=None, grid=None,

@@ -50,6 +50,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .. import KERNEL_NAMES
 from . import stencil
 from .kernel import (check_strip, compiler_params, cps_lanes, expander, mm,
                      refine, roll, shear, skew, strip_width, sweep,
@@ -228,6 +229,7 @@ def build_bwd(batch: int, Lx: int, Ly: int, *, T: int, lam1: int, lam2: int,
         scratch_shapes=[vmem_scratch((W, T)) for _ in range(6)],
         compiler_params=compiler_params(W, T, 6),
         interpret=interpret,
+        name=KERNEL_NAMES["bwd"],
     )
     return lambda delta, delta_next, cps, gbar: call(
         delta, delta_next, cps, gbar.reshape(1, batch))

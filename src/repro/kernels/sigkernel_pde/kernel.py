@@ -56,6 +56,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .. import KERNEL_NAMES
 from . import stencil
 
 LANES = 128
@@ -353,6 +354,7 @@ def build_fwd(batch: int, Lx: int, Ly: int, *, T: int, lam1: int, lam2: int,
         scratch_shapes=[vmem_scratch((W, T)), vmem_scratch((W, T))],
         compiler_params=compiler_params(W, T, 2),
         interpret=interpret,
+        name=KERNEL_NAMES["fwd_ckpt" if save_cps else "fwd"],
     )
 
     def run(delta):
@@ -383,6 +385,7 @@ def build_fwd_fused(batch: int, Lx: int, Ly: int, d: int, *, T: int,
         scratch_shapes=[vmem_scratch((W, T)), vmem_scratch((W, T))],
         compiler_params=compiler_params(W, T, 2),
         interpret=interpret,
+        name=KERNEL_NAMES["fwd_fused"],
     )
     return lambda dx, dy: call(dx, dy)[0]
 
@@ -411,6 +414,7 @@ def build_gram_fused(Bx: int, By: int, Lx: int, Ly: int, d: int, *, T: int,
         scratch_shapes=[vmem_scratch((W, T)), vmem_scratch((W, T))],
         compiler_params=compiler_params(W, T, 2),
         interpret=interpret,
+        name=KERNEL_NAMES["gram_fused"],
     )
     return lambda dX, dY: call(dX, dY)[:, 0, :]
 

@@ -158,15 +158,17 @@ def _solve_fused_fwd(dx, dy, lam1, lam2, launch, scheme="order1",
 
 def _delta_pullback(dd, dx, dy):
     """Pull ∂F/∂Δ back through Δ = dx · dyᵀ onto the increments."""
-    ddx = jnp.einsum("...ij,...jd->...id", dd, dy.astype(dd.dtype))
-    ddy = jnp.einsum("...ij,...id->...jd", dd, dx.astype(dd.dtype))
-    return ddx.astype(dx.dtype), ddy.astype(dy.dtype)
+    with jax.named_scope("repro.pde.pullback"):
+        ddx = jnp.einsum("...ij,...jd->...id", dd, dy.astype(dd.dtype))
+        ddy = jnp.einsum("...ij,...id->...jd", dd, dx.astype(dd.dtype))
+        return ddx.astype(dx.dtype), ddy.astype(dy.dtype)
 
 
 def _solve_fused_bwd(lam1, lam2, launch, scheme, interior_dtype, res, gbar):
     dx, dy = res
-    delta = jnp.einsum("bid,bjd->bij", dx.astype(jnp.float32),
-                       dy.astype(jnp.float32))
+    with jax.named_scope("repro.pde.pullback"):
+        delta = jnp.einsum("bid,bjd->bij", dx.astype(jnp.float32),
+                           dy.astype(jnp.float32))
     _, cps = solve_with_grid(delta, lam1, lam2, launch, scheme,
                              interior_dtype)
     dd = solve_grad(delta, cps, gbar, lam1, lam2, launch, scheme,
@@ -215,15 +217,17 @@ def _gram_fused_bwd(lam1, lam2, launch, scheme, interior_dtype, res, gbar):
     # row-blocking the Gram (repro.core.gram), which confines this to one
     # block at a time.
     dX, dY = res
-    delta = jnp.einsum("aid,bjd->abij", dX.astype(jnp.float32),
-                       dY.astype(jnp.float32))
+    with jax.named_scope("repro.pde.pullback"):
+        delta = jnp.einsum("aid,bjd->abij", dX.astype(jnp.float32),
+                           dY.astype(jnp.float32))
     _, cps = solve_with_grid(delta, lam1, lam2, launch, scheme,
                              interior_dtype)
     dd = solve_grad(delta, cps, gbar, lam1, lam2, launch, scheme,
                     interior_dtype)
-    ddX = jnp.einsum("abij,bjd->aid", dd, dY.astype(dd.dtype))
-    ddY = jnp.einsum("abij,aid->bjd", dd, dX.astype(dd.dtype))
-    return ddX.astype(dX.dtype), ddY.astype(dY.dtype)
+    with jax.named_scope("repro.pde.pullback"):
+        ddX = jnp.einsum("abij,bjd->aid", dd, dY.astype(dd.dtype))
+        ddY = jnp.einsum("abij,aid->bjd", dd, dX.astype(dd.dtype))
+        return ddX.astype(dX.dtype), ddY.astype(dY.dtype)
 
 
 gram_fused.defvjp(_gram_fused_fwd, _gram_fused_bwd)

@@ -38,6 +38,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.tensoralg import level_offsets, level_sizes, sig_dim
+from repro.kernels import KERNEL_NAMES
 
 
 def vmem_scratch(shape, dtype=jnp.float32):
@@ -103,4 +104,5 @@ def build_horner(n_tiles: int, Lp: int, d: int, depth: int, *, BT: int,
         out_shape=jax.ShapeDtypeStruct((n_tiles, sd, BT), jnp.float32),
         scratch_shapes=[vmem_scratch((sd, BT))],
         interpret=interpret,
+        name=KERNEL_NAMES["horner"],
     )

@@ -128,7 +128,8 @@ def _interval_kernel(prefix: jax.Array, inv_prefix: jax.Array,
     inv_left = _gather(inv_prefix, jnp.maximum(ql - 1, 0))
     inv_left = jnp.where((ql > 0)[..., None], inv_left,
                          jnp.zeros((), inv_left.dtype))
-    return ta.chen(inv_left, q_right, d, depth)
+    with jax.named_scope("repro.stream.combine"):
+        return ta.chen(inv_left, q_right, d, depth)
 
 
 @functools.partial(jax.jit, static_argnames=("depth", "lead_lag"))
@@ -197,8 +198,9 @@ def _update_kernel(points: jax.Array, prefix: jax.Array,
         * jnp.ones((1, prefix.shape[-1]), jnp.int32), axis=-2)
     q_m = jnp.broadcast_to(q_m, s_chunk.shape)
     inv_q_m = jnp.broadcast_to(inv_q_m, s_chunk.shape)
-    new_q = ta.chen(q_m, s_chunk, d_t, depth)
-    new_inv = ta.chen(inv_chunk, inv_q_m, d_t, depth)      # (ab)⁻¹ = b⁻¹a⁻¹
+    with jax.named_scope("repro.stream.combine"):
+        new_q = ta.chen(q_m, s_chunk, d_t, depth)
+        new_inv = ta.chen(inv_chunk, inv_q_m, d_t, depth)  # (ab)⁻¹ = b⁻¹a⁻¹
     record_combines(2 * mc)
 
     # scatter the mc new prefixes at offset m, the chunk at offset length
@@ -248,8 +250,9 @@ def _evict_kernel(points: jax.Array, prefix: jax.Array,
     iq = _gather(inv_prefix, sidx)
     piv_q = jnp.broadcast_to(_gather(prefix, (t - 1)[None]), q.shape)
     piv_i = jnp.broadcast_to(_gather(inv_prefix, (t - 1)[None]), q.shape)
-    new_prefix = ta.chen(piv_i, q, d, depth)
-    new_inv = ta.chen(iq, piv_q, d, depth)
+    with jax.named_scope("repro.stream.combine"):
+        new_prefix = ta.chen(piv_i, q, d, depth)
+        new_inv = ta.chen(iq, piv_q, d, depth)
     record_combines(2 * M)
     return new_points, new_prefix, new_inv, length - e
 

@@ -517,3 +517,23 @@ def test_gradients_flow_through_evict():
     # evicted points cancel through the inverse splice (up to f32 round-off)
     np.testing.assert_allclose(np.asarray(g[:3]), 0.0, atol=1e-5)
     assert float(jnp.abs(g[5:]).max()) > 0
+
+
+@pytest.mark.parametrize("kernel", ["query", "evict"])
+def test_chen_combines_carry_their_profile_scope(kernel):
+    """The combines show as ``repro.stream.combine`` in a profile's
+    ``op_name`` (docs/solver_guide.md, "Reading a profile")."""
+    from repro.core.tensoralg import sig_dim
+    from repro.stream import path as sp
+    d, depth, M = 2, 3, 8
+    store = jax.ShapeDtypeStruct((M, sig_dim(d, depth)), jnp.float32)
+    n = jax.ShapeDtypeStruct((4,), jnp.int32)
+    i = jax.ShapeDtypeStruct((), jnp.int32)
+    if kernel == "query":
+        lowered = sp._interval_kernel.lower(store, store, n, n, d=d,
+                                            depth=depth)
+    else:
+        pts = jax.ShapeDtypeStruct((M + 1, d), jnp.float32)
+        lowered = sp._evict_kernel.lower(pts, store, store, i, i, C=M + 1,
+                                         M=M, f=1, d=d, depth=depth)
+    assert "/repro.stream.combine/" in lowered.compile().as_text()

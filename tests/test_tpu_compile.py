@@ -5,16 +5,25 @@ scalar VMEM stores, ...); these compiles catch that without a chip.  Shapes
 are those of ``chip_smoke.py``: the sig-MMD step (B=128 pairs, L=128, d=3 +
 time, dyadic order 1) and the signature features (B=128, L=1024, d=5,
 depth 5).  The topology is described inside a fixture, never at import.
+
+Two whole programs are compiled besides, the MMD training step and the
+sharded symmetric Gram, to pin the names a profile reads: every kernel's
+custom call carries a name from ``repro.kernels.KERNEL_NAMES``, and the
+engine's ops carry its ``jax.named_scope`` layers in their ``op_name``.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
+import repro
 from repro.core.tensoralg import sig_dim
+from repro.kernels import KERNEL_NAMES
+from repro.kernels.sigkernel_pde import ops as pde_ops
 from repro.kernels.sigkernel_pde.grad_kernel import build_bwd
 from repro.kernels.sigkernel_pde.kernel import (build_fwd, build_fwd_fused,
                                                 build_gram_fused, cps_lanes,
@@ -47,6 +56,18 @@ def one_chip(topo):
     yield SingleDeviceSharding(topo.devices[0])
     jax.config.update("jax_enable_compilation_cache", was)
     cc.reset_cache()
+
+
+@pytest.fixture
+def compiled_kernels(one_chip, monkeypatch):
+    """The ``ops.py`` wrappers build Mosaic kernels, not interpreted ones.
+
+    Their jitted traces do not key on the interpret flag, so the caches are
+    cleared on the way in and out: no trace crosses into another test."""
+    monkeypatch.setattr(pde_ops, "interpret_mode", lambda: False)
+    jax.clear_caches()
+    yield
+    jax.clear_caches()
 
 
 def _compiles_to_mosaic(fn, sharding, *shapes):
@@ -108,3 +129,52 @@ def test_signature_horner_compiles(one_chip):
                           interpret=False)
     assert sig_dim(d, depth) == 3905
     _compiles_to_mosaic(horner, one_chip, (Bs // BT, Lp, d, BT))
+
+
+CUSTOM_CALL = re.compile(r"^\s*%(\S+) = .*custom_call_target=\"tpu_custom_call\"",
+                         re.M)
+
+
+def _named_layers(text):
+    """(kernel roles, op_name strings) of a compiled program's text; each
+    kernel's custom call must be named from ``KERNEL_NAMES``."""
+    names = [re.sub(r"\.\d+$", "", n) for n in CUSTOM_CALL.findall(text)]
+    assert names and set(names) <= set(KERNEL_NAMES.values()), names
+    roles = {n.split(".")[1] for n in names}
+    return roles, set(re.findall(r'op_name="([^"]*)"', text))
+
+
+def _passes_through(op_names, scopes):
+    """The scopes that are a part of some ``op_name`` path, bare or inside a
+    transformation (``jvp(repro.gram.pairs)``)."""
+    return {s for s in scopes
+            if any(re.search(rf"(^|[/(]){re.escape(s)}([/)]|$)", n)
+                   for n in op_names)}
+
+
+def test_mmd_step_names_its_kernels_and_layers(compiled_kernels, one_chip):
+    sk = repro.SigKernel(transforms=repro.TransformPipeline(time_aug=True),
+                         grid=repro.GridConfig(1, 1), backend="pallas_fused")
+    step = jax.value_and_grad(lambda x, y: sk.mmd2(x, y))
+    x = jax.ShapeDtypeStruct((8, 32, 3), jnp.float32, sharding=one_chip)
+    roles, op_names = _named_layers(
+        jax.jit(step).lower(x, x).compile().as_text())
+    assert roles == {"fwd", "fwd_ckpt", "bwd"}
+    scopes = {"repro.transform", "repro.gram.pairs", "repro.pde.pullback",
+              "repro.gram.reduce"}
+    assert _passes_through(op_names, scopes) == scopes
+
+
+def test_sharded_gram_names_its_kernels_and_layers(compiled_kernels, topo):
+    from repro.launch.mesh import make_gram_mesh
+    mesh = make_gram_mesh(4, devices=topo.devices)
+    z = jax.ShapeDtypeStruct((16, 32, 8), jnp.float32,
+                             sharding=NamedSharding(mesh, PartitionSpec()))
+    gram = jax.jit(lambda Z: repro.sigkernel_gram_sharded(
+        Z, mesh=mesh, grid=repro.GridConfig(0, 0), backend="pallas_fused",
+        row_block=4))
+    roles, op_names = _named_layers(gram.lower(z).compile().as_text())
+    assert roles == {"fwd"}
+    scopes = {"repro.transform", "repro.gram.pairs", "repro.gram.shard",
+              "repro.gram.reduce"}
+    assert _passes_through(op_names, scopes) == scopes
