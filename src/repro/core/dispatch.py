@@ -539,6 +539,23 @@ class count_pair_solves(_op_counter):
     _slot = "pair"
 
 
+class count_packed_slots(_op_counter):
+    """Counts the pair slots of the packed PDE kernels: each fused kernel
+    reports its programs × pairs per program, so ``c.total`` is the slots
+    solved, ``c.pairs`` the real pairs among them (the rest are padding)
+    and ``c.fill`` their ratio."""
+
+    _slot = "pack"
+
+    def __init__(self):
+        super().__init__()
+        self.pairs = 0
+
+    @property
+    def fill(self) -> float:
+        return self.pairs / self.total if self.total else 1.0
+
+
 class count_scan_steps(_op_counter):
     """Counts signature Horner-scan steps (one per increment folded).
 
@@ -561,6 +578,14 @@ class count_combines(_op_counter):
 def record_pair_solves(n: int) -> None:
     """Report ``n`` PDE pair-solves to the active counter (no-op otherwise)."""
     _record("pair", n)
+
+
+def record_packed_slots(slots: int, pairs: int) -> None:
+    """Report ``slots`` packed-kernel slots holding ``pairs`` real pairs."""
+    _record("pack", slots)
+    active = getattr(_count_state, "pack", None)
+    if active is not None:
+        active.pairs += int(pairs)
 
 
 def record_scan_steps(n: int) -> None:

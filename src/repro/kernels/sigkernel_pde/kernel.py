@@ -5,7 +5,8 @@ TPU-native translation of the paper's GPU wavefront scheme:
 * the PDE grid is swept in **row strips of T refined rows** (T ≤ 128 lanes)
   — the analogue of the paper's 32-thread blocks;
 * inside a strip the anti-diagonal wavefront advances one skew-step per loop
-  iteration: step t computes the (1, T) row of cells (r, c = t − r), lane r,
+  iteration: step t computes the (1, T) row of cells (r, c = t − r), lane r
+  ((P, T) in the fused kernels, below),
   carrying the two previous rows (``prev``, ``prev2``) — the analogue of the
   paper's 3 rotating anti-diagonals in CUDA shared memory;
 * every wavefront row is stored in a ``(W, T)`` VMEM scratch at sublane t.
@@ -28,6 +29,17 @@ Grid = (batch, n_strips); TPU grid iteration is sequential per core, so VMEM
 scratch persists across strips — the TPU-native replacement for CUDA
 inter-block synchronisation.  Kernel values are written as lanes of one
 resident lane-dense output row.
+
+The fused forward kernels pack P pairs into each program (``fused_pack``:
+``PACK`` = 16, fewer for long paths and small batches): grid = (batch/P,
+n_strips), or (Bx, By/P, n_strips) for the Gram, and every wavefront row
+is a (P, T) block, one pair per sublane.  The scratches are (W·P, T), rows
+t·P … t·P + P − 1 holding skew-step t of the P pairs, so the sweep loads,
+computes and stores whole vregs at sublane-aligned rows; Δ and its skew
+are still built pair by pair.  Every pair of a call shares ny, T and λ, so
+the lane masks are the one-pair program's, and each pair's result is
+bitwise that of P = 1.  Each program writes its P values to P lanes of
+the lane-dense output row, in the (1, 128) block its pack falls in.
 
 In grad mode the kernel additionally emits one **checkpoint** per strip:
 lanes T−CR … T−1 (CR = min(T, 8)) of the stored rows of the strip above,
@@ -62,6 +74,13 @@ from . import stencil
 LANES = 128
 #: checkpoint lanes kept per strip (an aligned sublane tile once transposed)
 CPS_LANES = 8
+#: pairs per program of the fused forward kernels, one per sublane: a
+#: wavefront row is two (8, 128) f32 vregs, two independent chains per step
+PACK = 16
+#: skew rows (pairs × W) a pack may hold: all 16 pairs up to W = 384,
+#: fewer for longer paths, whose unrolled Δ build grows the program and
+#: its compile time with P·W
+PACK_ROWS = 16 * 384
 
 
 def strip_width(ny: int, T: int) -> int:
@@ -73,19 +92,34 @@ def strip_width(ny: int, T: int) -> int:
     return -(-(ny + T + 1) // LANES) * LANES
 
 
+def fused_pack(ny: int, T: int, batch: int) -> int:
+    """Pairs per program of the fused kernels: ``PACK``, halved while the
+    pack's skew rows P·W pass ``PACK_ROWS`` or half the pack would hold
+    all ``batch`` pairs (a lone pair keeps the one-pair program)."""
+    W = strip_width(ny, T)
+    P = PACK
+    while P > 1 and (P * W > PACK_ROWS or P >= 2 * batch):
+        P //= 2
+    return P
+
+
 def cps_lanes(T: int) -> int:
     return min(T, CPS_LANES)
 
 
-def vmem_limit(W: int, T: int, n_buffers: int) -> int:
+def vmem_limit(W: int, T: int, n_buffers: int, pack: int = 1) -> int:
     """Scoped-VMEM request: ``n_buffers`` (W, T) scratches plus as many
-    (T, W) temporaries, lanes padded to 128, doubled for headroom."""
-    est = 4 * W * max(T, LANES) * (2 * n_buffers)
-    return min(96 * 2 ** 20, max(32 * 2 ** 20, est))
+    (T, W) temporaries, lanes padded to 128, doubled for headroom; packed
+    scratches, (W·P, T), add their P − 1 further pairs on top."""
+    row_bytes = 4 * W * max(T, LANES)
+    est = row_bytes * (2 * n_buffers)
+    base = min(96 * 2 ** 20, max(32 * 2 ** 20, est))
+    return base + row_bytes * n_buffers * (pack - 1)
 
 
-def compiler_params(W: int, T: int, n_buffers: int):
-    return pltpu.CompilerParams(vmem_limit_bytes=vmem_limit(W, T, n_buffers))
+def compiler_params(W: int, T: int, n_buffers: int, pack: int = 1):
+    return pltpu.CompilerParams(
+        vmem_limit_bytes=vmem_limit(W, T, n_buffers, pack))
 
 
 def roll(x: jax.Array, shift: int) -> jax.Array:
@@ -154,30 +188,41 @@ def skew(M: jax.Array) -> jax.Array:
 
 def sweep(s_ref, src_ref, dst_ref, *, T, lam1, lam2, ny, scheme="order1",
           interior_dtype="float32"):
-    """Anti-diagonal sweep of one strip.
+    """Anti-diagonal sweep of one strip of P pairs at once.
 
-    s_ref:   (W, T) skewed refined Δ of the strip.
-    src_ref: (W, T) wavefront rows of the strip above (ones above the first
-             strip); only lanes T−1 and T−2 are read.
-    dst_ref: (W, T) receives this strip's wavefront rows; may be ``src_ref``
-             (reads lead writes).
-    Returns the last row, whose lane T−1 is k̂[strip_bottom, ny].
+    P is read from the scratch shapes, (W·P, T) for W = ``strip_width(ny,
+    T)``: rows t·P … t·P + P − 1 hold skew-step t of the P pairs, so a
+    wavefront step is one (P, T) row, a pair per sublane.  Every pair of a
+    call shares ny, T and λ, so the lane masks broadcast over the pairs.
+
+    s_ref:   (W·P, T) skewed refined Δ of the strip.
+    src_ref: (W·P, T) wavefront rows of the strip above (ones above the
+             first strip); only lanes T−1 and T−2 are read.
+    dst_ref: (W·P, T) receives this strip's wavefront rows; may be
+             ``src_ref`` (reads lead writes).
+    Returns the last (P, T) row, whose lane T−1 is k̂[strip_bottom, ny] of
+    each pair.
     """
-    W = s_ref.shape[0]
+    W = strip_width(ny, T)
+    P = s_ref.shape[0] // W
     order2 = scheme == "order2"
     m1, m2 = (1 << lam1) - 1, (1 << lam2) - 1
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (P, T), 1)
+
+    def at(i):
+        # sublane-aligned start of step i's P rows
+        return pl.ds(i, 1) if P == 1 else pl.ds(pl.multiple_of(i * P, P), P)
 
     def above(i):
         # lane T−1 of row i is k̂[strip_top, i − T + 2] and lane T−2 is
         # k̂[strip_top − 1, i − T + 3]; rows past the end only feed
         # inactive lanes
-        return src_ref[pl.ds(jnp.minimum(i, W - 1), 1), :]
+        return src_ref[at(jnp.minimum(i, W - 1)), :]
 
     def step(t, carry):
-        prev, prev2, above_prev = carry                # (1, T) f32
+        prev, prev2, above_prev = carry                # (P, T) f32
         above_t = above(t + T - 1)
-        p = s_ref[pl.ds(t, 1), :]                      # anti-diagonal of Δ
+        p = s_ref[at(t), :]                            # anti-diagonal of Δ
         shift_prev = jnp.where(lane == 0, roll(above_t, 1), roll(prev, 1))
         shift_prev2 = jnp.where(lane == 0, roll(above_prev, 1),
                                 roll(prev2, 1))
@@ -203,35 +248,69 @@ def sweep(s_ref, src_ref, dst_ref, *, T, lam1, lam2, ny, scheme="order1",
         cur = stencil.round_interior(cur, interior_dtype)
         active = (lane <= t) & (lane > t - ny)
         cur = jnp.where(active, cur, 0.0)
-        dst_ref[pl.ds(t, 1), :] = cur
+        dst_ref[at(t), :] = cur
         return (cur, prev, above_t)
 
-    zeros = zeros_row(s_ref)
+    zeros = zeros_row(s_ref, P)
     last, _, _ = jax.lax.fori_loop(0, ny + T - 1, step,
                                    (zeros, zeros, above(max(T - 2, 0))))
     return last
 
 
-def zeros_row(ref) -> jax.Array:
-    """A (1, T) row of zeros computed from row 0 of ``ref`` (finite).
+def zeros_row(ref, P: int = 1) -> jax.Array:
+    """A (P, T) row of zeros computed from rows 0 … P − 1 of ``ref``
+    (finite).
 
     Mosaic lays a loop carry out like its initial value; a constant's
     replicated layout cannot take the computed rows the loop yields.
     """
-    return ref[pl.ds(0, 1), :] * 0.0 + 0.0
+    return ref[pl.ds(0, P), :] * 0.0 + 0.0
+
+
+def readout(last: jax.Array) -> jax.Array:
+    """(P, T) last wavefront row -> (P, 1) column of its lanes T−1."""
+    T = last.shape[-1]
+    lane = jax.lax.broadcasted_iota(jnp.int32, last.shape, 1)
+    return jnp.sum(jnp.where(lane == T - 1, last, 0.0), axis=1, keepdims=True)
 
 
 def place(row: jax.Array, idx, last: jax.Array) -> jax.Array:
     """Lane-dense output row with lane ``idx`` := lane T−1 of ``last``."""
-    T = last.shape[-1]
-    lane = jax.lax.broadcasted_iota(jnp.int32, last.shape, 1)
-    k = jnp.sum(jnp.where(lane == T - 1, last, 0.0), axis=1, keepdims=True)
+    k = readout(last)
     pos = jax.lax.broadcasted_iota(jnp.int32, row.shape, row.ndim - 1)
     return jnp.where(pos == idx, k, row)
 
 
-def _strip(M, s_ref, rows_ref, cps_ref, *, strip_axis, **kw):
-    """Shared strip body: reset at the first strip, checkpoint, skew, sweep."""
+def place_pack(row: jax.Array, off, last: jax.Array) -> jax.Array:
+    """(1, 128) output row with lanes off … off + P − 1 := lanes T−1 of
+    the P rows of ``last``: each sublane's value moves to its lane by one
+    select and a sum over the sublanes, in which it is the only term."""
+    k = readout(last)                                  # (P, 1)
+    shape = (k.shape[0], row.shape[-1])
+    pos = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+    sub = jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+    vals = jnp.sum(jnp.where(pos == off + sub, k, 0.0), axis=0,
+                   keepdims=True)
+    lane = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1)
+    mine = (lane >= off) & (lane < off + k.shape[0])
+    return jnp.where(mine, vals, row)
+
+
+def pack_lanes(batch: int) -> int:
+    """Lanes of a packed kernel's output row: ``batch`` rounded up to whole
+    (1, 128) blocks, so each pack writes one vreg of a lane-dense row."""
+    return -(-batch // LANES) * LANES
+
+
+def pack_block(b, P: int):
+    """(output block, lane offset) of pack ``b`` in its row."""
+    per_block = LANES // P
+    return b // per_block, (b % per_block) * P
+
+
+def _strip(block, P, s_ref, rows_ref, cps_ref, *, strip_axis, **kw):
+    """Shared strip body: reset at the first strip, checkpoint, skew the
+    (T, W) Δ block ``block(j)`` of each pair j < P into its rows, sweep."""
     @pl.when(pl.program_id(strip_axis) == 0)
     def _reset():
         rows_ref[...] = jnp.ones_like(rows_ref)
@@ -239,7 +318,12 @@ def _strip(M, s_ref, rows_ref, cps_ref, *, strip_axis, **kw):
     if cps_ref is not None:
         CR = cps_ref.shape[2]
         cps_ref[0, 0] = rows_ref[...].T[-CR:]
-    s_ref[...] = skew(M)
+    if P == 1:
+        s_ref[...] = skew(block(0))
+    else:
+        W = s_ref.shape[0] // P
+        for j in range(P):
+            s_ref[pl.ds(j, W, stride=P), :] = skew(block(j))
     return sweep(s_ref, rows_ref, rows_ref, **kw)
 
 
@@ -258,8 +342,8 @@ def fwd_kernel(delta_ref, out_ref, *refs, T: int, lam1: int, lam2: int,
     """
     cps_ref, s_ref, rows_ref = refs if save_cps else (None, *refs)
     M = refine(delta_ref[0], T, s_ref.shape[0], lam1, lam2)
-    last = _strip(M, s_ref, rows_ref, cps_ref, strip_axis=1, T=T, lam1=lam1,
-                  lam2=lam2, ny=ny, scheme=scheme,
+    last = _strip(lambda _: M, 1, s_ref, rows_ref, cps_ref, strip_axis=1,
+                  T=T, lam1=lam1, lam2=lam2, ny=ny, scheme=scheme,
                   interior_dtype=interior_dtype)
     out_ref[...] = place(out_ref[...], pl.program_id(0), last)
 
@@ -267,30 +351,43 @@ def fwd_kernel(delta_ref, out_ref, *refs, T: int, lam1: int, lam2: int,
 def fused_fwd_kernel(dx_ref, dy_ref, out_ref, s_ref, rows_ref, *, T: int,
                      lam1: int, lam2: int, ny: int, scheme: str = "order1",
                      interior_dtype: str = "float32"):
-    """Fused-Δ forward: the strip's Δ block is computed in VMEM from the
-    increments (an (R, d) × (d, Ly) MXU matmul) — Δ never exists in HBM.
+    """Fused-Δ forward: program (pack, strip) solves the P pairs of a pack.
+
+    dx_ref: (P, R, d) and dy_ref: (P, Ly, d) increments of the pack; each
+    pair's Δ block is computed in VMEM (an (R, d) × (d, Ly) MXU matmul) —
+    Δ never exists in HBM.  out_ref: the (1, 128) block of the output row
+    that holds the pack's P lanes, rewritten every strip; the last strip's
+    write is the result.
 
     Beyond-paper variant: pySigLib precomputes Δ with one bmm (design
     choice (2)).  Whether recomputing Δ beats streaming it from HBM on a
     TPU is not measured.
     """
-    M = refine_fused(dx_ref[0], dy_ref[0], T, s_ref.shape[0], lam1, lam2)
-    last = _strip(M, s_ref, rows_ref, None, strip_axis=1, T=T, lam1=lam1,
-                  lam2=lam2, ny=ny, scheme=scheme,
-                  interior_dtype=interior_dtype)
-    out_ref[...] = place(out_ref[...], pl.program_id(0), last)
+    W = strip_width(ny, T)
+    last = _strip(
+        lambda j: refine_fused(dx_ref[j], dy_ref[j], T, W, lam1, lam2),
+        dx_ref.shape[0], s_ref, rows_ref, None, strip_axis=1, T=T,
+        lam1=lam1, lam2=lam2, ny=ny, scheme=scheme,
+        interior_dtype=interior_dtype)
+    _, off = pack_block(pl.program_id(0), dx_ref.shape[0])
+    out_ref[...] = place_pack(out_ref[...], off, last)
 
 
 def fused_gram_kernel(dx_ref, dy_ref, out_ref, s_ref, rows_ref, *, T: int,
                       lam1: int, lam2: int, ny: int, scheme: str = "order1",
                       interior_dtype: str = "float32"):
-    """Fused-Δ Gram: program (a, b, strip) solves pair (x_a, y_b); out_ref
-    is the resident (1, 1, By) Gram row a."""
-    M = refine_fused(dx_ref[0], dy_ref[0], T, s_ref.shape[0], lam1, lam2)
-    last = _strip(M, s_ref, rows_ref, None, strip_axis=2, T=T, lam1=lam1,
-                  lam2=lam2, ny=ny, scheme=scheme,
-                  interior_dtype=interior_dtype)
-    out_ref[0] = place(out_ref[0], pl.program_id(1), last)
+    """Fused-Δ Gram: program (a, pack, strip) solves pairs (x_a, y_b) for
+    the P columns b of the pack; dx_ref is (1, R, d), dy_ref (P, Ly, d) and
+    out_ref the (1, 1, 128) block of Gram row a that holds the pack's
+    lanes."""
+    W = strip_width(ny, T)
+    last = _strip(
+        lambda j: refine_fused(dx_ref[0], dy_ref[j], T, W, lam1, lam2),
+        dy_ref.shape[0], s_ref, rows_ref, None, strip_axis=2, T=T,
+        lam1=lam1, lam2=lam2, ny=ny, scheme=scheme,
+        interior_dtype=interior_dtype)
+    _, off = pack_block(pl.program_id(1), dy_ref.shape[0])
+    out_ref[0] = place_pack(out_ref[0], off, last)
 
 
 def check_strip(T: int, lam1: int, Lx: int, scheme: str = "order1") -> int:
@@ -364,59 +461,88 @@ def build_fwd(batch: int, Lx: int, Ly: int, *, T: int, lam1: int, lam2: int,
     return run
 
 
+def check_pack(batch: int, pack: int) -> int:
+    """Packs of ``pack`` pairs in ``batch``; ops.py pads the batch."""
+    if LANES % pack:
+        raise ValueError(f"{pack} pairs per program do not divide {LANES}")
+    if batch % pack:
+        raise ValueError(
+            f"batch={batch} is not a multiple of the {pack} pairs per "
+            f"program — the ops.py wrappers pad it with zero increments")
+    return batch // pack
+
+
 def build_fwd_fused(batch: int, Lx: int, Ly: int, d: int, *, T: int,
                     lam1: int, lam2: int, interpret: bool,
-                    scheme: str = "order1", interior_dtype: str = "float32"):
-    """Fused-Δ forward: ``f(dx (B, Lx, d), dy (B, Ly, d)) -> k (B,)``."""
+                    scheme: str = "order1", interior_dtype: str = "float32",
+                    pack: int | None = None):
+    """Fused-Δ forward: ``f(dx (B, Lx, d), dy (B, Ly, d)) -> k (B,)``.
+
+    Grid (B/P, n_strips): each program solves a pack of P pairs, one per
+    sublane of every (P, T) wavefront row, and writes their values to P
+    lanes of a lane-dense output row.  P is ``fused_pack``'s unless
+    ``pack`` says otherwise; B must be a multiple of P (ops.py pads it).
+    """
     R = check_strip(T, lam1, Lx, scheme)
     n_strips = Lx // R
     ny = Ly << lam2
     W = strip_width(ny, T)
+    pack = pack or fused_pack(ny, T, batch)
+    n_packs = check_pack(batch, pack)
     kern = functools.partial(fused_fwd_kernel, T=T, lam1=lam1, lam2=lam2,
                              ny=ny, scheme=scheme,
                              interior_dtype=interior_dtype)
     call = pl.pallas_call(
         kern,
-        grid=(batch, n_strips),
-        in_specs=[pl.BlockSpec((1, R, d), lambda b, s: (b, s, 0)),
-                  pl.BlockSpec((1, Ly, d), lambda b, s: (b, 0, 0))],
-        out_specs=pl.BlockSpec((1, batch), lambda b, s: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((1, batch), jnp.float32),
-        scratch_shapes=[vmem_scratch((W, T)), vmem_scratch((W, T))],
-        compiler_params=compiler_params(W, T, 2),
+        grid=(n_packs, n_strips),
+        in_specs=[pl.BlockSpec((pack, R, d), lambda b, s: (b, s, 0)),
+                  pl.BlockSpec((pack, Ly, d), lambda b, s: (b, 0, 0))],
+        out_specs=pl.BlockSpec(
+            (1, LANES), lambda b, s: (0, pack_block(b, pack)[0])),
+        out_shape=jax.ShapeDtypeStruct((1, pack_lanes(batch)), jnp.float32),
+        scratch_shapes=[vmem_scratch((W * pack, T)),
+                        vmem_scratch((W * pack, T))],
+        compiler_params=compiler_params(W, T, 2, pack),
         interpret=interpret,
         name=KERNEL_NAMES["fwd_fused"],
     )
-    return lambda dx, dy: call(dx, dy)[0]
+    return lambda dx, dy: call(dx, dy)[0, :batch]
 
 
 def build_gram_fused(Bx: int, By: int, Lx: int, Ly: int, d: int, *, T: int,
                      lam1: int, lam2: int, interpret: bool,
-                     scheme: str = "order1", interior_dtype: str = "float32"):
+                     scheme: str = "order1", interior_dtype: str = "float32",
+                     pack: int | None = None):
     """Fused-Δ Gram ``f(dX (Bx, Lx, d), dY (By, Ly, d)) -> (Bx, By)``: grid
-    over (row path, col path, strip); dx/dy blocks are fetched from the
-    ORIGINAL increment arrays by index map — neither Δ nor any pairwise
-    replication of the paths ever exists in HBM."""
+    over (row path, pack of P col paths, strip), P as in
+    ``build_fwd_fused``; dx/dy blocks are fetched from the ORIGINAL
+    increment arrays by index map — neither Δ nor any pairwise replication
+    of the paths ever exists in HBM.  By must be a multiple of P (ops.py
+    pads it)."""
     R = check_strip(T, lam1, Lx, scheme)
     n_strips = Lx // R
     ny = Ly << lam2
     W = strip_width(ny, T)
+    pack = pack or fused_pack(ny, T, By)
+    n_packs = check_pack(By, pack)
     kern = functools.partial(fused_gram_kernel, T=T, lam1=lam1, lam2=lam2,
                              ny=ny, scheme=scheme,
                              interior_dtype=interior_dtype)
     call = pl.pallas_call(
         kern,
-        grid=(Bx, By, n_strips),
+        grid=(Bx, n_packs, n_strips),
         in_specs=[pl.BlockSpec((1, R, d), lambda a, b, s: (a, s, 0)),
-                  pl.BlockSpec((1, Ly, d), lambda a, b, s: (b, 0, 0))],
-        out_specs=pl.BlockSpec((1, 1, By), lambda a, b, s: (a, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((Bx, 1, By), jnp.float32),
-        scratch_shapes=[vmem_scratch((W, T)), vmem_scratch((W, T))],
-        compiler_params=compiler_params(W, T, 2),
+                  pl.BlockSpec((pack, Ly, d), lambda a, b, s: (b, 0, 0))],
+        out_specs=pl.BlockSpec(
+            (1, 1, LANES), lambda a, b, s: (a, 0, pack_block(b, pack)[0])),
+        out_shape=jax.ShapeDtypeStruct((Bx, 1, pack_lanes(By)), jnp.float32),
+        scratch_shapes=[vmem_scratch((W * pack, T)),
+                        vmem_scratch((W * pack, T))],
+        compiler_params=compiler_params(W, T, 2, pack),
         interpret=interpret,
         name=KERNEL_NAMES["gram_fused"],
     )
-    return lambda dX, dY: call(dX, dY)[:, 0, :]
+    return lambda dX, dY: call(dX, dY)[:, 0, :By]
 
 
 def vmem_scratch(shape, dtype=jnp.float32):

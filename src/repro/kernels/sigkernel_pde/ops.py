@@ -7,6 +7,9 @@ Responsibilities:
   solution invariant because A(0) = B(0) = 1, so padding is exact — and the
   padded problem's *exact* adjoint restricted to the real Δ block is the real
   problem's exact adjoint),
+* zero-padding the batch of the fused kernels to whole packs of
+  ``kernel.fused_pack`` pairs (zero increments give k = 1 pairs, sliced
+  off),
 * strip-height (T) selection,
 * interpret-mode selection (TPU: compiled; elsewhere interpret=True).
 """
@@ -18,8 +21,9 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ...core import dispatch
 from .. import interpret_mode
-from .kernel import build_fwd
+from .kernel import build_fwd, build_fwd_fused, build_gram_fused, fused_pack
 from .grad_kernel import build_bwd
 
 _MAX_T = 128
@@ -123,22 +127,39 @@ def solve_grad(delta: jax.Array, cps: jax.Array, gbar: jax.Array,
 # recomputes strip interiors from the forward's checkpoint rows.
 # ---------------------------------------------------------------------------
 
+def _pad_rows(a: jax.Array, axis: int, n: int) -> jax.Array:
+    """Zero-pad ``a`` along ``axis`` to length ``n``: zero increments give
+    zero Δ rows or pairs, exact no-ops (k = 1) that the caller drops."""
+    pad = n - a.shape[axis]
+    if not pad:
+        return a
+    widths = [(0, 0)] * a.ndim
+    widths[axis] = (0, pad)
+    return jnp.pad(a, widths)
+
+
+def _packed(n: int, Ly: int, lam2: int, T: int) -> int:
+    """``n`` pairs rounded up to whole packs of the fused kernels."""
+    P = fused_pack(Ly << lam2, T, n)
+    return -(-n // P) * P
+
+
 @functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6))
 def _solve_fused_impl(dx: jax.Array, dy: jax.Array, lam1: int,
                       lam2: int, launch=None, scheme: str = "order1",
                       interior_dtype: str = "float32") -> jax.Array:
-    from .kernel import build_fwd_fused
     B, Lx, d = dx.shape
     Ly = dy.shape[1]
     T = choose_T(lam1, _max_t(launch))
-    R = T >> lam1
-    pad = (-Lx) % R
-    if pad:  # zero increments -> zero Δ rows -> exact no-ops
-        dx = jnp.pad(dx, ((0, 0), (0, pad), (0, 0)))
-    call = build_fwd_fused(B, Lx + pad, Ly, d, T=T, lam1=lam1, lam2=lam2,
+    Lxp = -(-Lx // (T >> lam1)) * (T >> lam1)
+    Bp = _packed(B, Ly, lam2, T)
+    dispatch.record_packed_slots(Bp, B)
+    dx = _pad_rows(_pad_rows(dx, 1, Lxp), 0, Bp)
+    dy = _pad_rows(dy, 0, Bp)
+    call = build_fwd_fused(Bp, Lxp, Ly, d, T=T, lam1=lam1, lam2=lam2,
                            interpret=interpret_mode(), scheme=scheme,
                            interior_dtype=interior_dtype)
-    return call(dx.astype(jnp.float32), dy.astype(jnp.float32))
+    return call(dx.astype(jnp.float32), dy.astype(jnp.float32))[:B]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6))
@@ -183,18 +204,18 @@ solve_fused.defvjp(_solve_fused_fwd, _solve_fused_bwd)
 def _gram_fused_impl(dX: jax.Array, dY: jax.Array, lam1: int,
                      lam2: int, launch=None, scheme: str = "order1",
                      interior_dtype: str = "float32") -> jax.Array:
-    from .kernel import build_gram_fused
     Bx, Lx, d = dX.shape
     By, Ly = dY.shape[0], dY.shape[1]
     T = choose_T(lam1, _max_t(launch))
-    R = T >> lam1
-    pad = (-Lx) % R
-    if pad:
-        dX = jnp.pad(dX, ((0, 0), (0, pad), (0, 0)))
-    call = build_gram_fused(Bx, By, Lx + pad, Ly, d, T=T, lam1=lam1,
+    Lxp = -(-Lx // (T >> lam1)) * (T >> lam1)
+    Byp = _packed(By, Ly, lam2, T)
+    dispatch.record_packed_slots(Bx * Byp, Bx * By)
+    dX = _pad_rows(dX, 1, Lxp)
+    dY = _pad_rows(dY, 0, Byp)
+    call = build_gram_fused(Bx, Byp, Lxp, Ly, d, T=T, lam1=lam1,
                             lam2=lam2, interpret=interpret_mode(), scheme=scheme,
                             interior_dtype=interior_dtype)
-    return call(dX.astype(jnp.float32), dY.astype(jnp.float32))
+    return call(dX.astype(jnp.float32), dY.astype(jnp.float32))[:, :By]
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6))

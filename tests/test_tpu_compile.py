@@ -4,7 +4,8 @@ Mosaic refuses layouts that interpret mode accepts (unaligned blocks,
 scalar VMEM stores, ...); these compiles catch that without a chip.  Shapes
 are those of ``chip_smoke.py``: the sig-MMD step (B=128 pairs, L=128, d=3 +
 time, dyadic order 1) and the signature features (B=128, L=1024, d=5,
-depth 5).  The topology is described inside a fixture, never at import.
+depth 5); the packed fused kernels compile at the benchmark cells' pair
+counts besides.  The topology is described inside a fixture, never at import.
 
 Two whole programs are compiled besides, the MMD training step and the
 sharded symmetric Gram, to pin the names a profile reads: every kernel's
@@ -25,9 +26,10 @@ from repro.core.tensoralg import sig_dim
 from repro.kernels import KERNEL_NAMES
 from repro.kernels.sigkernel_pde import ops as pde_ops
 from repro.kernels.sigkernel_pde.grad_kernel import build_bwd
-from repro.kernels.sigkernel_pde.kernel import (build_fwd, build_fwd_fused,
+from repro.kernels.sigkernel_pde.kernel import (PACK, build_fwd,
+                                                build_fwd_fused,
                                                 build_gram_fused, cps_lanes,
-                                                strip_width)
+                                                fused_pack, strip_width)
 from repro.kernels.signature.kernel import build_horner
 from repro.kernels.signature.ops import choose_BT
 
@@ -97,17 +99,49 @@ def test_pde_forward_compiles(one_chip, scheme, interior_dtype, lam,
     _compiles_to_mosaic(fwd, one_chip, (B, Lx, L))
 
 
-def test_pde_fused_forward_compiles(one_chip):
-    Lx, _, _ = _geometry(1)
-    fwd = build_fwd_fused(B, Lx, L, D, T=T, lam1=1, lam2=1, interpret=False)
-    _compiles_to_mosaic(fwd, one_chip, (B, Lx, D), (B, L, D))
+#: the benchmark cells' fused geometries: (pairs, channels, λ) of the
+#: pooled Gram's row blocks (d=8, order 0) and of the MMD step's triangles
+#: (d=3 plus time, order 1)
+FUSED_CELLS = [(16384, 8, 0), (128 * 129 // 2, 4, 1)]
 
 
-def test_pde_fused_gram_compiles(one_chip):
-    Lx, _, _ = _geometry(1)
-    gram = build_gram_fused(B, B, Lx, L, D, T=T, lam1=1, lam2=1,
+@pytest.mark.parametrize("pairs,d,lam", FUSED_CELLS)
+def test_pde_fused_forward_compiles(one_chip, pairs, d, lam):
+    Lx, _, _ = _geometry(lam)
+    assert pairs % PACK == 0
+    fwd = build_fwd_fused(pairs, Lx, L, d, T=T, lam1=lam, lam2=lam,
+                          interpret=False)
+    _compiles_to_mosaic(fwd, one_chip, (pairs, Lx, d), (pairs, L, d))
+
+
+@pytest.mark.parametrize("Bx,By,d,lam", [(16, 1024, 8, 0), (128, 128, 4, 1)])
+def test_pde_fused_gram_compiles(one_chip, Bx, By, d, lam):
+    Lx, _, _ = _geometry(lam)
+    gram = build_gram_fused(Bx, By, Lx, L, d, T=T, lam1=lam, lam2=lam,
                             interpret=False)
-    _compiles_to_mosaic(gram, one_chip, (B, Lx, D), (B, L, D))
+    _compiles_to_mosaic(gram, one_chip, (Bx, Lx, d), (By, L, d))
+
+
+def test_pde_fused_long_path_compiles(one_chip):
+    """A path of 512 points at dyadic order 1 (W = 1152) packs 4 pairs,
+    whose unrolled Δ build stays within ``PACK_ROWS``."""
+    Lx, Ly, lam = 128, 511, 1
+    assert fused_pack(Ly << lam, T, B) == 4
+    fwd = build_fwd_fused(B, Lx, Ly, D, T=T, lam1=lam, lam2=lam,
+                          interpret=False)
+    _compiles_to_mosaic(fwd, one_chip, (B, Lx, D), (B, Ly, D))
+
+
+def test_pde_fused_gram_output_is_lane_dense(one_chip):
+    """The packed Gram writes its values along the lanes of (Bx, 1, By)
+    rows: no more HBM than one (8, 128) tile per 128 values of a row."""
+    n = 4096
+    gram = build_gram_fused(n, n, 128, L, D, T=T, lam1=0, lam2=0,
+                            interpret=False)
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+            for s in [(n, 128, D), (n, L, D)]]
+    mem = jax.jit(gram).lower(*args).compile().memory_analysis()
+    assert mem.temp_size_in_bytes <= 8 * 4 * n * n + 2 ** 20
 
 
 @pytest.mark.parametrize("scheme,interior_dtype,lam", [
