@@ -199,8 +199,9 @@ class LaunchConfig:
         Must be a power of two; still shrunk to fit the VMEM budget and
         clamped to at least one unrefined row (``1 << lam1``).
       sig_bt: batch-tile (lane) cap of the signature Horner kernel
-        (default 128 = ``kernels.signature.ops._MAX_BT``). Power of two;
-        still shrunk to fit the VMEM budget.
+        (default 128 = ``kernels.signature.ops._MAX_BT``). Power of two.
+        A signature too deep for the VMEM budget is split by leading
+        letters, not by narrower tiles, which would save no VMEM.
       sig_lb: length-block of the signature Horner kernel's grid
         (default 256 = ``kernels.signature.ops._LB``). Power of two.
       gram_row_block: Gram-engine row blocking (``row_block=``) applied

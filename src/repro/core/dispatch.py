@@ -556,6 +556,23 @@ class count_packed_slots(_op_counter):
         return self.pairs / self.total if self.total else 1.0
 
 
+class count_horner_lanes(_op_counter):
+    """Counts the lanes of the Horner signature kernel: each launch reports
+    its batch tiles × 128 lanes (the width of a VMEM tile, whatever the
+    tile's own), so ``c.total`` is the lanes swept, ``c.paths`` the real
+    paths among them and ``c.fill`` their ratio."""
+
+    _slot = "lanes"
+
+    def __init__(self):
+        super().__init__()
+        self.paths = 0
+
+    @property
+    def fill(self) -> float:
+        return self.paths / self.total if self.total else 1.0
+
+
 class count_scan_steps(_op_counter):
     """Counts signature Horner-scan steps (one per increment folded).
 
@@ -586,6 +603,14 @@ def record_packed_slots(slots: int, pairs: int) -> None:
     active = getattr(_count_state, "pack", None)
     if active is not None:
         active.pairs += int(pairs)
+
+
+def record_horner_lanes(lanes: int, paths: int) -> None:
+    """Report ``lanes`` Horner-kernel lanes holding ``paths`` real paths."""
+    _record("lanes", lanes)
+    active = getattr(_count_state, "lanes", None)
+    if active is not None:
+        active.paths += int(paths)
 
 
 def record_scan_steps(n: int) -> None:

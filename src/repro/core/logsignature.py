@@ -46,10 +46,17 @@ def logsignature_dim(d: int, depth: int, mode: str = "lyndon") -> int:
     return lyndon.logsig_dim(d, depth)
 
 
+def _log(sig: jax.Array, d: int, depth: int) -> jax.Array:
+    """The truncated log of flat signatures, under its profile scope."""
+    with jax.named_scope("repro.logsig.log"):
+        return ta.tensor_log(sig, d, depth)
+
+
 def _project(flat_log: jax.Array, d: int, depth: int, mode: str) -> jax.Array:
     if mode == "expand":
         return flat_log
-    return lyndon.compress(flat_log, d, depth, mode)
+    with jax.named_scope("repro.logsig.project"):
+        return lyndon.compress(flat_log, d, depth, mode)
 
 
 # ---------------------------------------------------------------------------
@@ -60,20 +67,20 @@ def _project(flat_log: jax.Array, d: int, depth: int, mode: str) -> jax.Array:
 def _logsignature_core(z: jax.Array, depth: int) -> jax.Array:
     """Flat (mode="expand") log-signature of an increment stream z (..., L-1, d)."""
     d = z.shape[-1]
-    return ta.tensor_log(_signature_horner_from_increments(z, depth), d, depth)
+    return _log(_signature_horner_from_increments(z, depth), d, depth)
 
 
 def _logsig_core_fwd(z, depth):
     sig = _signature_horner_from_increments(z, depth)
     d = z.shape[-1]
-    return ta.tensor_log(sig, d, depth), (z, sig)
+    return _log(sig, d, depth), (z, sig)
 
 
 def _logsig_core_bwd(depth, res, g):
     z, sig = res
     d = z.shape[-1]
     # pull the cotangent back through the (pointwise-polynomial) log ...
-    _, log_vjp = jax.vjp(lambda s: ta.tensor_log(s, d, depth), sig)
+    _, log_vjp = jax.vjp(lambda s: _log(s, d, depth), sig)
     (g_sig,) = log_vjp(g)
     # ... then reuse the O(1)-memory time-reversed deconstruction of §2.4.
     return _signature_core_bwd(depth, (z, sig), g_sig)
@@ -154,8 +161,7 @@ def logsignature(path: jax.Array, depth: int, *, mode: str = "lyndon",
                 "implementation — the streamed prefix scan is pure JAX; "
                 "pass backend='auto' or backend='reference'")
         sig_stream = _signature_stream_from_increments(z, depth)
-        flat_log = ta.tensor_log(sig_stream, d, depth)
-        return _project(flat_log, d, depth, mode)
+        return _project(_log(sig_stream, d, depth), d, depth, mode)
     key_shape = (z.shape[-2], z.shape[-1], depth)
     backend = dispatch.resolve(
         backend, op="logsignature", shape=key_shape, dtype=z.dtype,

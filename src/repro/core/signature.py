@@ -163,9 +163,10 @@ def _signature_core_bwd(depth, res, g):
         g_before, g_z = vjp(g_after)
         return (s_before, g_before), g_z
 
-    zs = jnp.moveaxis(z, -2, 0)
-    (_, _), g_z = jax.lax.scan(body, (sig, g), zs, reverse=True)
-    return (jnp.moveaxis(g_z, 0, -2),)
+    with jax.named_scope("repro.signature.bwd"):
+        zs = jnp.moveaxis(z, -2, 0)
+        (_, _), g_z = jax.lax.scan(body, (sig, g), zs, reverse=True)
+        return (jnp.moveaxis(g_z, 0, -2),)
 
 
 _signature_core.defvjp(_signature_core_fwd, _signature_core_bwd)

@@ -24,7 +24,7 @@ KERNEL_NAMES = {
     "fwd_fused": "sigkernel_pde.fwd._solve_fused_impl",
     "gram_fused": "sigkernel_pde.fwd._gram_fused_impl",
     "bwd": "sigkernel_pde.bwd._grad_flat",
-    "horner": "signature.horner",
+    "horner": "signature.horner._horner_flat",
 }
 
 

@@ -1,10 +1,11 @@
 """Jit'd public wrapper for the Horner signature Pallas kernel.
 
 Handles batch/length padding (zero increments are exact no-ops), the
-(batch, L, d) -> (tiles, L, d, BT) layout transform, batch-tile sizing under
-the VMEM budget, and exact backprop: the backward pass is the time-reversed
-signature deconstruction of pySigLib §2.4 (O(1) memory in path length),
-reusing the validated pure-JAX implementation in ``repro.core.signature``.
+(batch, L, d) -> (tiles, L, d, BT) layout transform, the split of deep
+signatures by leading letters under the VMEM budget, and exact backprop:
+the backward pass is the time-reversed signature deconstruction of
+pySigLib §2.4 (O(1) memory in path length), reusing the validated pure-JAX
+implementation in ``repro.core.signature``.
 """
 
 from __future__ import annotations
@@ -14,11 +15,11 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from repro.core.tensoralg import sig_dim, level_sizes
+from repro.core.tensoralg import sig_dim
 from .. import interpret_mode
-from .kernel import build_horner
+from .kernel import LANES, assemble, build_horner, vmem_bytes
 
-_VMEM_BUDGET = 10 * 1024 * 1024
+_VMEM_BUDGET = 16 * 1024 * 1024
 _MAX_BT = 128
 _LB = 256
 
@@ -30,32 +31,36 @@ def default_use_pallas() -> bool:
     return not interpret_mode()
 
 
-def choose_BT(d: int, depth: int, LB: int, max_bt: int = _MAX_BT) -> int:
-    """Largest batch tile ≤ ``max_bt`` whose working set fits the VMEM budget."""
-    sd = sig_dim(d, depth)
-    bmax = d ** max(depth - 1, 1)
-    BT = max_bt
-    while BT > 8:
-        if 4 * BT * (2 * sd + 2 * bmax + LB * d) <= _VMEM_BUDGET:
-            break
-        BT //= 2
-    return BT
+def choose_prefix(d: int, depth: int, LB: int, BT: int) -> int:
+    """Fewest leading letters by which the Horner kernel splits the words so
+    that one program's working set fits the VMEM budget.  A tile takes
+    (8, 128) VMEM tiles whatever its width, so the batch tile stays whole
+    and the levels are split instead."""
+    prefix = 0
+    while (prefix < depth - 1
+           and vmem_bytes(d, depth, prefix, LB, BT) > _VMEM_BUDGET):
+        prefix += 1
+    return prefix
 
 
 @functools.partial(jax.jit, static_argnums=(1, 2))
 def _horner_flat(z: jax.Array, depth: int, launch=None) -> jax.Array:
+    from repro.core import dispatch
     from repro.core.config import resolve_launch
     launch = resolve_launch(launch)
     B, Lm1, d = z.shape
     LB = min(launch.sig_lb or _LB, max(Lm1, 1))
-    BT = choose_BT(d, depth, LB, max_bt=launch.sig_bt or _MAX_BT)
+    BT = min(launch.sig_bt or _MAX_BT, _MAX_BT)
+    prefix = choose_prefix(d, depth, LB, BT)
     Bp = -(-B // BT) * BT
     Lp = -(-Lm1 // LB) * LB
     zp = jnp.pad(z.astype(jnp.float32), ((0, Bp - B), (0, Lp - Lm1), (0, 0)))
     n_tiles = Bp // BT
+    dispatch.record_horner_lanes(n_tiles * -(-BT // LANES) * LANES, B)
     zt = zp.reshape(n_tiles, BT, Lp, d).transpose(0, 2, 3, 1)  # (t, L, d, BT)
-    out = build_horner(n_tiles, Lp, d, depth, BT=BT, LB=LB,
+    out = build_horner(n_tiles, Lp, d, depth, BT=BT, LB=LB, prefix=prefix,
                        interpret=interpret_mode())(zt)
+    out = assemble(out, d, depth, prefix)                       # (t, sd, BT)
     sd = sig_dim(d, depth)
     return out.transpose(0, 2, 1).reshape(Bp, sd)[:B]
 
@@ -107,10 +112,9 @@ def logsignature_from_increments(z: jax.Array, depth: int,
     reuse the exact time-reversed deconstruction backward of the signature
     kernel wrapper via autodiff composition.
     """
-    from repro.core.logsignature import MODES, _project
-    from repro.core.tensoralg import tensor_log
+    from repro.core.logsignature import MODES, _log, _project
     if mode not in MODES:
         raise ValueError(f"mode must be one of {MODES}, got {mode!r}")
     d = z.shape[-1]
     sig = signature_from_increments(z, depth, launch)
-    return _project(tensor_log(sig, d, depth), d, depth, mode)
+    return _project(_log(sig, d, depth), d, depth, mode)

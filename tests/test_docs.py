@@ -59,7 +59,7 @@ def test_documented_symbols_exist():
               "solve_goursat_grad", "solve_goursat_grad_pde_approx",
               "delta_matrix"]),
         (ops, ["signature_from_increments", "logsignature_from_increments",
-               "default_use_pallas", "choose_BT"]),
+               "default_use_pallas", "choose_prefix"]),
     ]:
         for name in names:
             assert hasattr(obj, name), (obj.__name__, name)

@@ -4,13 +4,15 @@ Mosaic refuses layouts that interpret mode accepts (unaligned blocks,
 scalar VMEM stores, ...); these compiles catch that without a chip.  Shapes
 are those of ``chip_smoke.py``: the sig-MMD step (B=128 pairs, L=128, d=3 +
 time, dyadic order 1) and the signature features (B=128, L=1024, d=5,
-depth 5); the packed fused kernels compile at the benchmark cells' pair
-counts besides.  The topology is described inside a fixture, never at import.
+depths 5 to 7); the packed fused kernels compile at the benchmark cells'
+pair counts besides.  The topology is described inside a fixture, never at
+import.
 
-Two whole programs are compiled besides, the MMD training step and the
-sharded symmetric Gram, to pin the names a profile reads: every kernel's
-custom call carries a name from ``repro.kernels.KERNEL_NAMES``, and the
-engine's ops carry its ``jax.named_scope`` layers in their ``op_name``.
+Three whole programs are compiled besides, the MMD training step, the
+sharded symmetric Gram and the log-signature layer's training step, to pin
+the names a profile reads: every kernel's custom call carries a name from
+``repro.kernels.KERNEL_NAMES``, and the engine's ops carry its
+``jax.named_scope`` layers in their ``op_name``.
 """
 
 import os
@@ -31,7 +33,8 @@ from repro.kernels.sigkernel_pde.kernel import (PACK, build_fwd,
                                                 build_gram_fused, cps_lanes,
                                                 fused_pack, strip_width)
 from repro.kernels.signature.kernel import build_horner
-from repro.kernels.signature.ops import choose_BT
+from repro.kernels.signature import ops as sig_ops
+from repro.kernels.signature.ops import choose_prefix
 
 B, L, D, T = 128, 127, 4, 128          # pairs, Δ rows/cols, channels, strip
 
@@ -67,6 +70,7 @@ def compiled_kernels(one_chip, monkeypatch):
     Their jitted traces do not key on the interpret flag, so the caches are
     cleared on the way in and out: no trace crosses into another test."""
     monkeypatch.setattr(pde_ops, "interpret_mode", lambda: False)
+    monkeypatch.setattr(sig_ops, "interpret_mode", lambda: False)
     jax.clear_caches()
     yield
     jax.clear_caches()
@@ -156,13 +160,27 @@ def test_pde_backward_compiles(one_chip, scheme, interior_dtype, lam):
                         (B, n_strips, cps_lanes(T), W), (B,))
 
 
-def test_signature_horner_compiles(one_chip):
-    Bs, Lp, d, depth, LB = 128, 1024, 5, 5, 256
-    BT = choose_BT(d, depth, LB)
+def _horner_compiles(sharding, depth):
+    Bs, Lp, d, LB, BT = 128, 1024, 5, 256, 128
+    prefix = choose_prefix(d, depth, LB, BT)
     horner = build_horner(Bs // BT, Lp, d, depth, BT=BT, LB=LB,
-                          interpret=False)
-    assert sig_dim(d, depth) == 3905
-    _compiles_to_mosaic(horner, one_chip, (Bs // BT, Lp, d, BT))
+                          prefix=prefix, interpret=False)
+    _compiles_to_mosaic(horner, sharding, (Bs // BT, Lp, d, BT))
+    return prefix
+
+
+def test_signature_horner_compiles(one_chip):
+    """Depth 5 holds the whole signature in one program."""
+    assert sig_dim(5, 5) == 3905
+    assert _horner_compiles(one_chip, 5) == 0
+
+
+@pytest.mark.parametrize("depth,prefix", [(6, 1), (7, 2)])
+def test_signature_horner_deep_compiles(one_chip, depth, prefix):
+    """Depths 6 and 7 (19,530 and 97,655 rows, more VMEM than a program
+    may take) split the words by their first letters, on full 128-lane
+    batch tiles."""
+    assert _horner_compiles(one_chip, depth) == prefix
 
 
 CUSTOM_CALL = re.compile(r"^\s*%(\S+) = .*custom_call_target=\"tpu_custom_call\"",
@@ -211,4 +229,32 @@ def test_sharded_gram_names_its_kernels_and_layers(compiled_kernels, topo):
     assert roles == {"fwd"}
     scopes = {"repro.transform", "repro.gram.pairs", "repro.gram.shard",
               "repro.gram.reduce"}
+    assert _passes_through(op_names, scopes) == scopes
+
+
+def test_logsig_step_names_its_kernels_and_layers(compiled_kernels,
+                                                  one_chip):
+    """The ``sigfeat_train`` cell's step at its own shapes: the Horner
+    kernel is the only custom call, and the backward, the log and the
+    Lyndon projection carry their scopes.  (Short paths compile slower:
+    XLA unrolls the backward's short scan.)"""
+    import json
+    from chipbench.units import logsig_sgd_step
+    path = os.path.join(os.path.dirname(os.path.dirname(__file__)),
+                        "chipbench", "configs",
+                        "logsig_fig1_d5_L1024_N7.json")
+    with open(path) as f:
+        cfg = dict(json.load(f), backend="pallas")
+    B, L, d, depth = cfg["batch"], cfg["length"], cfg["channels"], cfg["depth"]
+    n = sum(logsig_sgd_step.feature_dims(d, depth))
+    loss = logsig_sgd_step.program_loss(cfg)
+    step = jax.value_and_grad(loss, has_aux=True)
+    S = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+         for s in [(d, d), (n,), (B, L, d), (B,)]]
+    with jax.default_matmul_precision(cfg["matmul_precision"]):
+        text = jax.jit(step).lower({"mix": S[0], "readout": S[1]},
+                                   *S[2:]).compile().as_text()
+    roles, op_names = _named_layers(text)
+    assert roles == {"horner"}
+    scopes = set(logsig_sgd_step.SCOPES)
     assert _passes_through(op_names, scopes) == scopes
