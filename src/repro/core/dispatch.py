@@ -556,6 +556,13 @@ class count_packed_slots(_op_counter):
         return self.pairs / self.total if self.total else 1.0
 
 
+class count_bwd_packed_slots(count_packed_slots):
+    """``count_packed_slots`` for the exact backward kernel, whose packs
+    the forward's counter does not count."""
+
+    _slot = "bwd_pack"
+
+
 class count_horner_lanes(_op_counter):
     """Counts the lanes of the Horner signature kernel: each launch reports
     its batch tiles × 128 lanes (the width of a VMEM tile, whatever the
@@ -597,10 +604,11 @@ def record_pair_solves(n: int) -> None:
     _record("pair", n)
 
 
-def record_packed_slots(slots: int, pairs: int) -> None:
-    """Report ``slots`` packed-kernel slots holding ``pairs`` real pairs."""
-    _record("pack", slots)
-    active = getattr(_count_state, "pack", None)
+def record_packed_slots(slots: int, pairs: int, slot: str = "pack") -> None:
+    """Report ``slots`` packed-kernel slots holding ``pairs`` real pairs
+    (``slot="bwd_pack"``: the exact backward's)."""
+    _record(slot, slots)
+    active = getattr(_count_state, slot, None)
     if active is not None:
         active.pairs += int(pairs)
 

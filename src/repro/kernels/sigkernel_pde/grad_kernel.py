@@ -17,6 +17,14 @@ full grid / recomputes fully).  The skewed dΔ is unskewed by the forward's
 row shear (rows stored in reverse order so the shear is a right rotation)
 and folded back onto the unrefined block by 0/1 matmuls.
 
+Like the fused forward, the backward packs P pairs into each program
+(``bwd_pack``: ``PACK`` = 16, fewer for long paths and small batches):
+grid = (batch/P, n_strips), and every wavefront row of both the recompute
+sweep and the adjoint sweep is a (P, T) block, one pair per sublane, in
+(W·P, T) scratches whose rows t·P … t·P + P − 1 hold skew-step t of the P
+pairs.  Δ, the checkpoint and dΔ are refined, skewed, sheared and folded
+pair by pair in a loop; each pair's dΔ is bitwise that of P = 1.
+
 Skew/lane conventions match ``kernel.py``:
 cell (r, c) := refined update (i, j) = (strip_top + r, c), value k̂[i+1, c+1],
 living at skew-step t = r + c, lane r.
@@ -52,27 +60,56 @@ from jax.experimental import pallas as pl
 
 from .. import KERNEL_NAMES
 from . import stencil
-from .kernel import (check_strip, compiler_params, cps_lanes, expander, mm,
-                     refine, roll, shear, skew, strip_width, sweep,
-                     vmem_scratch, zeros_row)
+from .kernel import (LANES, check_pack, check_strip, compiler_params,
+                     cps_lanes, expander, fused_pack, mm, pack_block,
+                     pack_lanes, pair_rows, refine, roll, shear, skew,
+                     step_rows, strip_width, sweep, vmem_scratch, zeros_row)
+
+#: skew rows (pairs × W) a backward pack may hold.  Its six (W·P, T)
+#: scratches take 3 KiB a row, so 8,192 rows hold 24 MiB and the VMEM
+#: request (``vmem_limit(W, T, 6, P)``) stays under 56 MiB: all 16 pairs
+#: up to W = 512, fewer for longer paths, one pair past W = 4,096
+BWD_PACK_ROWS = 16 * 512
+
+
+def bwd_pack(ny: int, T: int, batch: int) -> int:
+    """Pairs per program of the exact backward: ``fused_pack``'s rule
+    under the backward's row budget."""
+    return fused_pack(ny, T, batch, BWD_PACK_ROWS)
+
+
+def per_pair(P: int, body) -> None:
+    """``body(j)`` for each pair j < P of a pack, in a loop: the program
+    holds one copy of the per-pair work whatever P is."""
+    if P == 1:
+        body(0)
+    else:
+        pl.loop(0, P)(body)
 
 
 def bwd_kernel(delta_ref, delta_next_ref, cps_ref, gbar_ref, ddelta_ref,
                s_ref, above_ref, ksk_ref, dn_ref, gst_ref, dsk_ref, *,
                T: int, lam1: int, lam2: int, ny: int,
                scheme: str = "order1", interior_dtype: str = "float32"):
-    """One (batch, reversed-strip) grid step of the exact backward pass.
+    """One (pack, reversed-strip) grid step of the exact backward pass.
 
-    delta_ref / delta_next_ref: (1, R, Ly) Δ of this strip / the strip below.
-    cps_ref:  (1, 1, CR, W) the forward's checkpoint of this strip.
-    gbar_ref: (1, batch) upstream cotangents.
-    Scratch, all (W, T): skewed Δ, rows of the strip above (from the
-    checkpoint), recomputed k̂ rows, the strip below's first two refined Δ
-    rows as columns, adjoint rows (carried between strips), skewed dΔ.
+    A program solves the P pairs of a pack, one per sublane of every (P, T)
+    wavefront row, as ``kernel.sweep`` does.
+    delta_ref / delta_next_ref: (P, R, Ly) Δ of this strip / the strip below.
+    cps_ref:  (P, 1, CR, W) the forward's checkpoints of this strip.
+    gbar_ref: the (1, 128) block of the upstream cotangent row that holds
+              the pack's P lanes.
+    ddelta_ref: (P, R, Ly) dΔ of this strip.
+    Scratch, all (W·P, T), rows t·P … t·P + P − 1 holding skew-step t of
+    the P pairs: skewed Δ, rows of the strip above (from the checkpoint),
+    recomputed k̂ rows, the strip below's first two refined Δ rows as
+    columns, adjoint rows (carried between strips), skewed dΔ.  Δ, the
+    checkpoint and dΔ are moved pair by pair, each to its strided rows.
     """
     b, s_rev = pl.program_id(0), pl.program_id(1)
-    W = s_ref.shape[0]
-    Ly = delta_ref.shape[2]
+    P, _, Ly = delta_ref.shape
+    W = strip_width(ny, T)
+    CR = cps_ref.shape[2]
     n_steps = ny + T - 1
     order2 = scheme == "order2"
     m1, m2 = (1 << lam1) - 1, (1 << lam2) - 1
@@ -81,16 +118,21 @@ def bwd_kernel(delta_ref, delta_next_ref, cps_ref, gbar_ref, ddelta_ref,
     def _reset():
         gst_ref[...] = jnp.zeros_like(gst_ref)
 
-    s_ref[...] = skew(refine(delta_ref[0], T, W, lam1, lam2))
-    CR = cps_ref.shape[2]
-    tail = cps_ref[0, 0]                                    # (CR, W)
-    if CR < T:
-        tail = jnp.concatenate([jnp.zeros((T - CR, W), jnp.float32), tail])
-    above_ref[...] = tail.T
-    # dn[c, 0] / dn[c, 1]: refined rows 0 / 1 of the strip below at column c
-    nxt = refine(delta_next_ref[0], T, W, lam1, lam2)
-    row = jax.lax.broadcasted_iota(jnp.int32, nxt.shape, 0)
-    dn_ref[...] = jnp.where(row < 2, nxt, 0.0).T
+    def prologue(j):
+        mine = pair_rows(j, P, W)
+        s_ref[mine] = skew(refine(delta_ref[j], T, W, lam1, lam2))
+        tail = cps_ref[j, 0]                                # (CR, W)
+        if CR < T:
+            tail = jnp.concatenate([jnp.zeros((T - CR, W), jnp.float32),
+                                    tail])
+        above_ref[mine] = tail.T
+        # dn[c, 0] / dn[c, 1]: refined rows 0 / 1 of the strip below at
+        # column c
+        nxt = refine(delta_next_ref[j], T, W, lam1, lam2)
+        row = jax.lax.broadcasted_iota(jnp.int32, nxt.shape, 0)
+        dn_ref[mine] = jnp.where(row < 2, nxt, 0.0).T
+
+    per_pair(P, prologue)
     dsk_ref[...] = jnp.zeros_like(dsk_ref)
 
     # ---- phase 1: recompute strip interior k̂ from the checkpoint -----------
@@ -98,13 +140,16 @@ def bwd_kernel(delta_ref, delta_next_ref, cps_ref, gbar_ref, ddelta_ref,
           scheme=scheme, interior_dtype=interior_dtype)
 
     # ---- phase 2: reverse adjoint wavefront --------------------------------
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
-    blane = jax.lax.broadcasted_iota(jnp.int32, gbar_ref.shape, 1)
-    gbar = jnp.sum(jnp.where(blane == b, gbar_ref[...], 0.0), axis=1,
-                   keepdims=True)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (P, T), 1)
+    # each sublane's cotangent: lane off + j of the pack's (1, 128) block
+    _, off = pack_block(b, P)
+    pos = jax.lax.broadcasted_iota(jnp.int32, (P, LANES), 1)
+    sub = jax.lax.broadcasted_iota(jnp.int32, (P, LANES), 0)
+    gbar = jnp.sum(jnp.where(pos == off + sub, gbar_ref[...], 0.0), axis=1,
+                   keepdims=True)                           # (P, 1)
 
     def rows(ref, i):
-        return ref[pl.ds(i, 1), :]
+        return ref[step_rows(i, P), :]
 
     def bstep(i, carry):
         t = n_steps - 1 - i
@@ -182,32 +227,44 @@ def bwd_kernel(delta_ref, delta_next_ref, cps_ref, gbar_ref, ddelta_ref,
             contrib = cur * ((k_left + k_up) * stencil.coeff_dA(p_c)
                              - k_upleft * stencil.coeff_dB1(p_c))
         contrib = jnp.where(active, contrib, 0.0)
-        dsk_ref[pl.ds(W - 1 - t, 1), :] = contrib           # reversed rows
-        gst_ref[pl.ds(t, 1), :] = cur
+        dsk_ref[step_rows(W - 1 - t, P), :] = contrib      # reversed rows
+        gst_ref[step_rows(t, P), :] = cur
         return (cur, gnext, g_q)
 
-    zeros = zeros_row(s_ref)
+    zeros = zeros_row(s_ref, P)
     jax.lax.fori_loop(0, n_steps, bstep, (zeros, zeros, rows(gst_ref, ny)))
 
     # ---- phase 3: unskew + dyadic fold -> unrefined dΔ block ----------------
     # dsk[W−1−t, r] holds cell (r, t − r): rotating lane-row r right by r
     # puts cell (r, c) at lane W−1−c, which the reversed expander folds.
-    dM = mm(shear(dsk_ref[...].T), expander(W, Ly, lam2, reverse=True))
-    if lam1:
-        dM = mm(expander(T, T >> lam1, lam1, transpose=True), dM)
-    ddelta_ref[0] = dM * 2.0 ** (-(lam1 + lam2))
+    fold_y = expander(W, Ly, lam2, reverse=True)
+
+    def epilogue(j):
+        dM = mm(shear(dsk_ref[pair_rows(j, P, W)].T), fold_y)
+        if lam1:
+            dM = mm(expander(T, T >> lam1, lam1, transpose=True), dM)
+        ddelta_ref[j] = dM * 2.0 ** (-(lam1 + lam2))
+
+    per_pair(P, epilogue)
 
 
 def build_bwd(batch: int, Lx: int, Ly: int, *, T: int, lam1: int, lam2: int,
               interpret: bool, scheme: str = "order1",
-              interior_dtype: str = "float32"):
+              interior_dtype: str = "float32", pack: int | None = None):
     """Exact backward: ``f(delta, delta, cps, gbar (batch,)) -> dΔ`` of
-    shape (batch, Lx, Ly); Δ is passed twice (this strip / the strip below)."""
+    shape (batch, Lx, Ly); Δ is passed twice (this strip / the strip below).
+
+    Grid (batch/P, n_strips), strips reversed: each program solves a pack
+    of P pairs, one per sublane.  P is ``bwd_pack``'s unless ``pack`` says
+    otherwise; batch must be a multiple of P (ops.py pads it).
+    """
     R = check_strip(T, lam1, Lx, scheme)
     n_strips = Lx // R
     ny = Ly << lam2
     W = strip_width(ny, T)
     CR = cps_lanes(T)
+    pack = pack or bwd_pack(ny, T, batch)
+    n_packs = check_pack(batch, pack)
     kern = functools.partial(bwd_kernel, T=T, lam1=lam1, lam2=lam2, ny=ny,
                              scheme=scheme, interior_dtype=interior_dtype)
 
@@ -216,20 +273,25 @@ def build_bwd(batch: int, Lx: int, Ly: int, *, T: int, lam1: int, lam2: int,
 
     call = pl.pallas_call(
         kern,
-        grid=(batch, n_strips),
+        grid=(n_packs, n_strips),
         in_specs=[
-            pl.BlockSpec((1, R, Ly), lambda b, s: (b, rev(s), 0)),
-            pl.BlockSpec((1, R, Ly),
+            pl.BlockSpec((pack, R, Ly), lambda b, s: (b, rev(s), 0)),
+            pl.BlockSpec((pack, R, Ly),
                          lambda b, s: (b, jnp.minimum(rev(s) + 1, n_strips - 1), 0)),
-            pl.BlockSpec((1, 1, CR, W), lambda b, s: (b, rev(s), 0, 0)),
-            pl.BlockSpec((1, batch), lambda b, s: (0, 0)),
+            pl.BlockSpec((pack, 1, CR, W), lambda b, s: (b, rev(s), 0, 0)),
+            pl.BlockSpec((1, LANES),
+                         lambda b, s: (0, pack_block(b, pack)[0])),
         ],
-        out_specs=pl.BlockSpec((1, R, Ly), lambda b, s: (b, rev(s), 0)),
+        out_specs=pl.BlockSpec((pack, R, Ly), lambda b, s: (b, rev(s), 0)),
         out_shape=jax.ShapeDtypeStruct((batch, Lx, Ly), jnp.float32),
-        scratch_shapes=[vmem_scratch((W, T)) for _ in range(6)],
-        compiler_params=compiler_params(W, T, 6),
+        scratch_shapes=[vmem_scratch((W * pack, T)) for _ in range(6)],
+        compiler_params=compiler_params(W, T, 6, pack),
         interpret=interpret,
         name=KERNEL_NAMES["bwd"],
     )
-    return lambda delta, delta_next, cps, gbar: call(
-        delta, delta_next, cps, gbar.reshape(1, batch))
+
+    def run(delta, delta_next, cps, gbar):
+        row = jnp.pad(gbar, (0, pack_lanes(batch) - batch))
+        return call(delta, delta_next, cps, row.reshape(1, -1))
+
+    return run

@@ -92,13 +92,13 @@ def strip_width(ny: int, T: int) -> int:
     return -(-(ny + T + 1) // LANES) * LANES
 
 
-def fused_pack(ny: int, T: int, batch: int) -> int:
+def fused_pack(ny: int, T: int, batch: int, rows: int = PACK_ROWS) -> int:
     """Pairs per program of the fused kernels: ``PACK``, halved while the
-    pack's skew rows P·W pass ``PACK_ROWS`` or half the pack would hold
-    all ``batch`` pairs (a lone pair keeps the one-pair program)."""
+    pack's skew rows P·W pass ``rows`` or half the pack would hold all
+    ``batch`` pairs (a lone pair keeps the one-pair program)."""
     W = strip_width(ny, T)
     P = PACK
-    while P > 1 and (P * W > PACK_ROWS or P >= 2 * batch):
+    while P > 1 and (P * W > rows or P >= 2 * batch):
         P //= 2
     return P
 
@@ -186,6 +186,18 @@ def skew(M: jax.Array) -> jax.Array:
     return shear(M).T
 
 
+def step_rows(i, P: int):
+    """Rows i·P … i·P + P − 1 of a (W·P, T) scratch, the P pairs'
+    skew-step i: a sublane-aligned slice."""
+    return pl.ds(i, 1) if P == 1 else pl.ds(pl.multiple_of(i * P, P), P)
+
+
+def pair_rows(j, P: int, W: int):
+    """Index of pair j's rows in a (W·P, T) scratch: every P-th row from
+    row j (all of them when P = 1)."""
+    return ... if P == 1 else (pl.ds(j, W, stride=P), slice(None))
+
+
 def sweep(s_ref, src_ref, dst_ref, *, T, lam1, lam2, ny, scheme="order1",
           interior_dtype="float32"):
     """Anti-diagonal sweep of one strip of P pairs at once.
@@ -209,20 +221,16 @@ def sweep(s_ref, src_ref, dst_ref, *, T, lam1, lam2, ny, scheme="order1",
     m1, m2 = (1 << lam1) - 1, (1 << lam2) - 1
     lane = jax.lax.broadcasted_iota(jnp.int32, (P, T), 1)
 
-    def at(i):
-        # sublane-aligned start of step i's P rows
-        return pl.ds(i, 1) if P == 1 else pl.ds(pl.multiple_of(i * P, P), P)
-
     def above(i):
         # lane T−1 of row i is k̂[strip_top, i − T + 2] and lane T−2 is
         # k̂[strip_top − 1, i − T + 3]; rows past the end only feed
         # inactive lanes
-        return src_ref[at(jnp.minimum(i, W - 1)), :]
+        return src_ref[step_rows(jnp.minimum(i, W - 1), P), :]
 
     def step(t, carry):
         prev, prev2, above_prev = carry                # (P, T) f32
         above_t = above(t + T - 1)
-        p = s_ref[at(t), :]                            # anti-diagonal of Δ
+        p = s_ref[step_rows(t, P), :]                  # anti-diagonal of Δ
         shift_prev = jnp.where(lane == 0, roll(above_t, 1), roll(prev, 1))
         shift_prev2 = jnp.where(lane == 0, roll(above_prev, 1),
                                 roll(prev2, 1))
@@ -248,7 +256,7 @@ def sweep(s_ref, src_ref, dst_ref, *, T, lam1, lam2, ny, scheme="order1",
         cur = stencil.round_interior(cur, interior_dtype)
         active = (lane <= t) & (lane > t - ny)
         cur = jnp.where(active, cur, 0.0)
-        dst_ref[at(t), :] = cur
+        dst_ref[step_rows(t, P), :] = cur
         return (cur, prev, above_t)
 
     zeros = zeros_row(s_ref, P)
@@ -318,12 +326,9 @@ def _strip(block, P, s_ref, rows_ref, cps_ref, *, strip_axis, **kw):
     if cps_ref is not None:
         CR = cps_ref.shape[2]
         cps_ref[0, 0] = rows_ref[...].T[-CR:]
-    if P == 1:
-        s_ref[...] = skew(block(0))
-    else:
-        W = s_ref.shape[0] // P
-        for j in range(P):
-            s_ref[pl.ds(j, W, stride=P), :] = skew(block(j))
+    W = s_ref.shape[0] // P
+    for j in range(P):
+        s_ref[pair_rows(j, P, W)] = skew(block(j))
     return sweep(s_ref, rows_ref, rows_ref, **kw)
 
 

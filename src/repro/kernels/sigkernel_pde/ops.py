@@ -9,7 +9,9 @@ Responsibilities:
   problem's exact adjoint),
 * zero-padding the batch of the fused kernels to whole packs of
   ``kernel.fused_pack`` pairs (zero increments give k = 1 pairs, sliced
-  off),
+  off), and that of the exact backward to whole packs of
+  ``grad_kernel.bwd_pack`` pairs (zero Δ and zero cotangent give a zero
+  adjoint, sliced off),
 * strip-height (T) selection,
 * interpret-mode selection (TPU: compiled; elsewhere interpret=True).
 """
@@ -24,7 +26,7 @@ import jax.numpy as jnp
 from ...core import dispatch
 from .. import interpret_mode
 from .kernel import build_fwd, build_fwd_fused, build_gram_fused, fused_pack
-from .grad_kernel import build_bwd
+from .grad_kernel import build_bwd, bwd_pack
 
 _MAX_T = 128
 
@@ -93,11 +95,15 @@ def _grad_flat(delta, cps, gbar, lam1, lam2, launch=None,
     B, Lx, Ly = delta.shape
     T = choose_T(lam1, _max_t(launch))
     delta, Lxp = _pad_batched(delta, T >> lam1)
-    call = build_bwd(B, Lxp, Ly, T=T, lam1=lam1, lam2=lam2,
+    P = bwd_pack(Ly << lam2, T, B)
+    Bp = -(-B // P) * P
+    dispatch.record_packed_slots(Bp, B, slot="bwd_pack")
+    delta, cps, gbar = (_pad_rows(a, 0, Bp) for a in (delta, cps, gbar))
+    call = build_bwd(Bp, Lxp, Ly, T=T, lam1=lam1, lam2=lam2,
                      interpret=interpret_mode(), scheme=scheme,
-                     interior_dtype=interior_dtype)
+                     interior_dtype=interior_dtype, pack=P)
     dd = call(delta, delta, cps, gbar)
-    return dd[:, :Lx, :]
+    return dd[:B, :Lx, :]
 
 
 def solve_grad(delta: jax.Array, cps: jax.Array, gbar: jax.Array,

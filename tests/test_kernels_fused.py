@@ -105,6 +105,56 @@ def test_packed_grads_match_reference():
         np.testing.assert_allclose(g_f, g_r, rtol=5e-4, atol=1e-5)
 
 
+@pytest.mark.parametrize("scheme,interior_dtype", [
+    ("order1", "float32"), ("order2", "bfloat16")])
+@pytest.mark.parametrize("lam", [(0, 0), (1, 1)])
+@pytest.mark.parametrize("batch", [1, 7, 8, 13])
+def test_packed_backward_matches_single_pair_bitwise(batch, lam, scheme,
+                                                     interior_dtype):
+    """The exact backward at two to ``PACK`` pairs per program gives each
+    pair the dΔ bits of the one-pair program.  Strips of T = 8 rows carry
+    the adjoint rows from strip to strip; padded pairs have zero Δ and a
+    zero cotangent."""
+    from repro.kernels.sigkernel_pde.grad_kernel import build_bwd
+    from repro.kernels.sigkernel_pde.kernel import PACK, build_fwd
+    T, Lx, Ly = 8, 12, 7
+    l1, l2 = lam
+    Lxp = -(-Lx // (T >> l1)) * (T >> l1)
+    delta = jax.random.normal(jax.random.PRNGKey(9), (batch, Lxp, Ly)) * 0.2
+    gbar = jax.random.normal(jax.random.PRNGKey(10), (batch,))
+    kw = dict(T=T, lam1=l1, lam2=l2, interpret=True, scheme=scheme,
+              interior_dtype=interior_dtype)
+    _, cps = build_fwd(batch, Lxp, Ly, save_cps=True, **kw)(delta)
+    one = build_bwd(batch, Lxp, Ly, pack=1, **kw)(delta, delta, cps, gbar)
+    assert np.all(np.isfinite(one)) and np.any(np.asarray(one) != 0)
+    for pack in (2, 4, 8, PACK):
+        bp = -(-batch // pack) * pack
+        d = _pad_batch(delta, bp)
+        packed = build_bwd(bp, Lxp, Ly, pack=pack, **kw)(
+            d, d, _pad_batch(cps, bp), _pad_batch(gbar, bp))[:batch]
+        assert packed.shape == one.shape
+        np.testing.assert_array_equal(np.asarray(packed), np.asarray(one))
+
+
+@pytest.mark.parametrize("backend", ["pallas", "pallas_fused"])
+def test_packed_backward_grads_match_reference(backend):
+    """``jax.grad`` through both Pallas backends, whose exact backward
+    packs 13 pairs into a padded pack of 16, matches the reference
+    backend's gradient."""
+    from repro.core.config import GridConfig
+    from repro.core.sigkernel import sigkernel
+    x, y = paths(11, 13, 11, 3), paths(12, 13, 10, 3)
+    w = jnp.linspace(0.5, 1.5, 13)
+
+    def loss(a, b, name):
+        return jnp.sum(w * sigkernel(a, b, grid=GridConfig(1, 1),
+                                     backend=name))
+
+    for g_p, g_r in zip(jax.grad(loss, (0, 1))(x, y, backend),
+                        jax.grad(loss, (0, 1))(x, y, "reference")):
+        np.testing.assert_allclose(g_p, g_r, rtol=5e-4, atol=1e-5)
+
+
 @pytest.mark.parametrize("pairs,slots", [
     (1, 1), (2, 2), (3, 4), (5, 8), (13, 16), (16, 16), (17, 32)])
 def test_count_packed_slots(pairs, slots):
@@ -120,8 +170,9 @@ def test_count_packed_slots(pairs, slots):
 
 def test_benchmark_cells_fill_their_packs():
     """The benchmark cells hand the packed kernels whole packs: the MMD
-    step's triangles (8,256 pairs) and K_xy (128 × 128), and the pooled
-    Gram's 16,384-pair chunks (1024 paths, ``row_block=16``)."""
+    step's triangles (8,256 pairs) and K_xy (128 × 128), forward and
+    backward, and the pooled Gram's 16,384-pair chunks (1024 paths,
+    ``row_block=16``)."""
     import repro
     from repro.core import dispatch
     from repro.launch.mesh import make_gram_mesh
@@ -138,9 +189,26 @@ def test_benchmark_cells_fill_their_packs():
                 backend="pallas_fused", row_block=16), (z,), 16 * 1024)]:
         ops._solve_fused_impl.clear_cache()   # the counter counts traces
         ops._gram_fused_impl.clear_cache()
-        with dispatch.count_packed_slots() as c:
+        ops._grad_flat.clear_cache()
+        with dispatch.count_packed_slots() as c, \
+                dispatch.count_bwd_packed_slots() as cb:
             jax.eval_shape(step, *arg)
         assert (c.total, c.fill) == (slots, 1.0)
+        # the backward differentiates K_xx's triangle and K_xy
+        assert (cb.total, cb.fill) == ((slots, 1.0) if len(arg) == 2
+                                       else (0, 1.0))
+
+
+@pytest.mark.parametrize("pairs,slots", [(1, 1), (3, 4), (13, 16), (17, 32)])
+def test_count_bwd_packed_slots(pairs, slots):
+    """The exact backward pads its batch to whole packs of ``bwd_pack``
+    pairs and counts them apart from the forward's."""
+    from repro.core import dispatch
+    dx = path_increments(paths(13, pairs, 6, 2))
+    ops._grad_flat.clear_cache()   # the counter counts traces
+    with dispatch.count_bwd_packed_slots() as c:
+        jax.eval_shape(jax.grad(lambda a: ops.solve_fused(a, dx).sum()), dx)
+    assert (c.total, c.pairs) == (slots, pairs)
 
 
 @pytest.mark.parametrize("Ly,lam,batch,pack", [
@@ -151,3 +219,18 @@ def test_fused_pack(Ly, lam, batch, pack):
     fewer."""
     from repro.kernels.sigkernel_pde.kernel import fused_pack
     assert fused_pack(Ly << lam, 128, batch) == pack
+
+
+@pytest.mark.parametrize("Ly,lam,batch,pack", [
+    (127, 1, 24640, 16), (255, 1, 16384, 8), (511, 1, 16384, 4),
+    (1023, 1, 32, 2), (2047, 1, 32, 1), (127, 0, 1, 1), (127, 0, 6, 8)])
+def test_bwd_pack(Ly, lam, batch, pack):
+    """The backward packs ``PACK`` pairs up to W = 512 and fewer for longer
+    paths, so its six scratches keep the VMEM request under 56 MiB; long
+    streams and lone pairs keep the one-pair program."""
+    from repro.kernels.sigkernel_pde.grad_kernel import bwd_pack
+    from repro.kernels.sigkernel_pde.kernel import strip_width, vmem_limit
+    assert bwd_pack(Ly << lam, 128, batch) == pack
+    if pack > 1:
+        W = strip_width(Ly << lam, 128)
+        assert vmem_limit(W, 128, 6, pack) < 56 * 2 ** 20

@@ -5,7 +5,8 @@ scalar VMEM stores, ...); these compiles catch that without a chip.  Shapes
 are those of ``chip_smoke.py``: the sig-MMD step (B=128 pairs, L=128, d=3 +
 time, dyadic order 1) and the signature features (B=128, L=1024, d=5,
 depths 5 to 7); the packed fused kernels compile at the benchmark cells'
-pair counts besides.  The topology is described inside a fixture, never at
+pair counts besides, and the packed backward at the MMD step's shape and on
+a longer path.  The topology is described inside a fixture, never at
 import.
 
 Three whole programs are compiled besides, the MMD training step, the
@@ -27,7 +28,7 @@ import repro
 from repro.core.tensoralg import sig_dim
 from repro.kernels import KERNEL_NAMES
 from repro.kernels.sigkernel_pde import ops as pde_ops
-from repro.kernels.sigkernel_pde.grad_kernel import build_bwd
+from repro.kernels.sigkernel_pde.grad_kernel import build_bwd, bwd_pack
 from repro.kernels.sigkernel_pde.kernel import (PACK, build_fwd,
                                                 build_fwd_fused,
                                                 build_gram_fused, cps_lanes,
@@ -148,15 +149,22 @@ def test_pde_fused_gram_output_is_lane_dense(one_chip):
     assert mem.temp_size_in_bytes <= 8 * 4 * n * n + 2 ** 20
 
 
-@pytest.mark.parametrize("scheme,interior_dtype,lam", [
-    ("order1", "float32", 1),
-    ("order2", "bfloat16", 2),
+@pytest.mark.parametrize("scheme,interior_dtype,lam,Ly,pack", [
+    ("order1", "float32", 1, L, PACK),
+    ("order2", "bfloat16", 2, L, 8),
+    # a path of 512 points at dyadic order 1 (W = 1152) packs 4 pairs
+    ("order1", "float32", 1, 511, 4),
 ])
-def test_pde_backward_compiles(one_chip, scheme, interior_dtype, lam):
-    Lx, n_strips, W = _geometry(lam)
-    bwd = build_bwd(B, Lx, L, T=T, lam1=lam, lam2=lam, interpret=False,
+def test_pde_backward_compiles(one_chip, scheme, interior_dtype, lam, Ly,
+                               pack):
+    """The exact backward packs its pairs into the sublanes: 16 at the MMD
+    step's shape (W = 384), fewer on longer paths."""
+    Lx, n_strips, _ = _geometry(lam)
+    W = strip_width(Ly << lam, T)
+    assert bwd_pack(Ly << lam, T, B) == pack
+    bwd = build_bwd(B, Lx, Ly, T=T, lam1=lam, lam2=lam, interpret=False,
                     scheme=scheme, interior_dtype=interior_dtype)
-    _compiles_to_mosaic(bwd, one_chip, (B, Lx, L), (B, Lx, L),
+    _compiles_to_mosaic(bwd, one_chip, (B, Lx, Ly), (B, Lx, Ly),
                         (B, n_strips, cps_lanes(T), W), (B,))
 
 
